@@ -1543,7 +1543,6 @@ ENTRY_POINTS: tuple[EntryPoint, ...] = (
             f"{_PKG}/dataflow/fixpoint.py",
             f"{_PKG}/parallel/mesh.py",
             f"{_PKG}/parallel/collectives.py",
-            f"{_PKG}/parallel/compat.py",
         ),
         axes=("nodes",),
         # one psum per iteration: the contribs combine (replicated state
@@ -1565,7 +1564,6 @@ ENTRY_POINTS: tuple[EntryPoint, ...] = (
             f"{_PKG}/dataflow/fixpoint.py",
             f"{_PKG}/parallel/mesh.py",
             f"{_PKG}/parallel/collectives.py",
-            f"{_PKG}/parallel/compat.py",
         ),
         axes=("nodes",),
         # all_gather(weighted ranks) + psum(dangling mass) + psum(delta)
@@ -1591,7 +1589,6 @@ ENTRY_POINTS: tuple[EntryPoint, ...] = (
             f"{_PKG}/dataflow/fixpoint.py",
             f"{_PKG}/parallel/mesh.py",
             f"{_PKG}/parallel/collectives.py",
-            f"{_PKG}/parallel/compat.py",
         ),
         axes=("nodes",),
         # one psum combines head + tail partials (replicated state needs
@@ -1617,7 +1614,6 @@ ENTRY_POINTS: tuple[EntryPoint, ...] = (
             f"{_PKG}/dataflow/fixpoint.py",
             f"{_PKG}/parallel/mesh.py",
             f"{_PKG}/parallel/collectives.py",
-            f"{_PKG}/parallel/compat.py",
         ),
         axes=("nodes",),
         # THE owned collective contract (ISSUE 15 acceptance): log2(d)
@@ -1649,7 +1645,6 @@ ENTRY_POINTS: tuple[EntryPoint, ...] = (
             f"{_PKG}/dataflow/fixpoint.py",
             f"{_PKG}/parallel/mesh.py",
             f"{_PKG}/parallel/collectives.py",
-            f"{_PKG}/parallel/compat.py",
         ),
         axes=("nodes",),
         # reduce-scatter exchange + psum(dangling mass) + psum(delta)
@@ -1669,7 +1664,6 @@ ENTRY_POINTS: tuple[EntryPoint, ...] = (
             f"{_PKG}/ops/boundary.py",
             f"{_PKG}/dataflow/fixpoint.py",
             f"{_PKG}/parallel/collectives.py",
-            f"{_PKG}/parallel/compat.py",
         ),
         axes=("nodes",),
         # two boundary butterflies (2·log2(d) ppermutes) + two pmax norms
@@ -1686,7 +1680,6 @@ ENTRY_POINTS: tuple[EntryPoint, ...] = (
             f"{_PKG}/ops/boundary.py",
             f"{_PKG}/dataflow/fixpoint.py",
             f"{_PKG}/parallel/collectives.py",
-            f"{_PKG}/parallel/compat.py",
         ),
         axes=("nodes",),
         # two boundary butterflies + the changed-count psum: 5 at d=4
@@ -1738,7 +1731,6 @@ ENTRY_POINTS: tuple[EntryPoint, ...] = (
             f"{_PKG}/ops/tfidf.py",
             f"{_PKG}/parallel/mesh.py",
             f"{_PKG}/parallel/collectives.py",
-            f"{_PKG}/parallel/compat.py",
             # the host loop is the staged pipeline now (ISSUE 10): a
             # change to the staging/commit discipline must re-verify the
             # sharded contracts (collective budget, shrink-chain compiles)

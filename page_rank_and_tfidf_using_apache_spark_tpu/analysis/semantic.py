@@ -18,7 +18,8 @@ four invariants are checked against the entry's declared budgets:
   a weak-type widening that makes CPU-test (x64 on) and TPU-prod (x64
   off) execute different dtypes.
 - **transfer-census** — host-callback equations (``pure_callback`` /
-  ``io_callback`` / ``debug_callback`` / infeed / outfeed) per traced
+  ``io_callback`` / ``debug_callback`` / ``debug_print`` / infeed /
+  outfeed) per traced
   step, gated against ``transfer_budget`` (default 0: a compiled step
   must never round-trip to host — closing the loop the lexical
   ``unguarded-host-sync`` rule opened).
@@ -85,7 +86,15 @@ SEMANTIC_RULES: dict[str, str] = {
 
 # Primitives that cross the host boundary from inside a compiled program.
 _CALLBACK_PRIMS = frozenset(
-    {"pure_callback", "io_callback", "debug_callback", "callback", "infeed", "outfeed"}
+    {
+        "pure_callback",
+        "io_callback",
+        "debug_callback",
+        "debug_print",
+        "callback",
+        "infeed",
+        "outfeed",
+    }
 )
 
 # Communication primitives (what collective_budget counts).  axis_index is
@@ -361,9 +370,9 @@ def _anchor_location(ep: EntryPoint, t: Traceable | None, root: Path) -> tuple[s
 
 
 def _x64_context():
-    from jax.experimental import enable_x64
+    import jax
 
-    return enable_x64()
+    return jax.enable_x64(True)
 
 
 def _analyze_entry(ep: EntryPoint, root: Path) -> list[Finding]:

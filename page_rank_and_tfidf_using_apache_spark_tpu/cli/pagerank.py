@@ -21,6 +21,7 @@ from page_rank_and_tfidf_using_apache_spark_tpu.io.graph import (
     synthetic_powerlaw,
 )
 from page_rank_and_tfidf_using_apache_spark_tpu.models.pagerank import run_pagerank
+from page_rank_and_tfidf_using_apache_spark_tpu.utils.compile_cache import enable_compile_cache
 from page_rank_and_tfidf_using_apache_spark_tpu.utils.config import (
     PageRankConfig,
     load_tuned_profile,
@@ -92,6 +93,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv: list[str] | None = None) -> int:
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     # The traced run covers the whole driver: manifest at startup, every
     # span/retry/checkpoint event flushed per-event to the JSONL trace,
     # run-end summary at exit (no-op without --trace-dir/GRAFT_TRACE_DIR).

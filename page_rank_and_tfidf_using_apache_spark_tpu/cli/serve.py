@@ -32,6 +32,7 @@ from page_rank_and_tfidf_using_apache_spark_tpu.serving import (
     TfidfServer,
     load_index,
 )
+from page_rank_and_tfidf_using_apache_spark_tpu.utils.compile_cache import enable_compile_cache
 from page_rank_and_tfidf_using_apache_spark_tpu.utils.config import (
     load_tuned_profile,
     tuned_config,
@@ -96,6 +97,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv: list[str] | None = None) -> int:
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     with obs.run("serve", trace_dir=args.trace_dir):
         return _main(args)
 

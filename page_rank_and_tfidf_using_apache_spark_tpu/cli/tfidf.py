@@ -27,6 +27,7 @@ from page_rank_and_tfidf_using_apache_spark_tpu.models.tfidf import (
     run_tfidf,
     run_tfidf_streaming,
 )
+from page_rank_and_tfidf_using_apache_spark_tpu.utils.compile_cache import enable_compile_cache
 from page_rank_and_tfidf_using_apache_spark_tpu.utils.config import (
     TfidfConfig,
     load_tuned_profile,
@@ -113,6 +114,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv: list[str] | None = None) -> int:
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     if args.mesh and not args.streaming:
         raise SystemExit("--mesh requires --streaming (chunked ingest)")
     # The traced run covers the whole driver: manifest at startup, every

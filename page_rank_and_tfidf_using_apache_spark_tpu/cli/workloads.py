@@ -25,6 +25,7 @@ from page_rank_and_tfidf_using_apache_spark_tpu.io.graph import (
     load_snap,
     synthetic_powerlaw,
 )
+from page_rank_and_tfidf_using_apache_spark_tpu.utils.compile_cache import enable_compile_cache
 from page_rank_and_tfidf_using_apache_spark_tpu.utils.config import (
     ComponentsConfig,
     HitsConfig,
@@ -84,6 +85,7 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main(argv: list[str] | None = None) -> int:
     args = build_parser().parse_args(argv)
+    enable_compile_cache()
     with obs.run(f"workload_{args.workload}", trace_dir=args.trace_dir):
         return _main(args)
 

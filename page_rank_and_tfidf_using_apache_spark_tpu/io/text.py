@@ -177,6 +177,21 @@ def load_corpus_dir(path: str) -> tuple[list[str], list[str]]:
     return docs, names
 
 
+def synthetic_corpus_lines(n_docs: int, tokens_per_doc: int, seed: int) -> list[str]:
+    """Seeded Zipf corpus at 20-Newsgroups shape (BASELINE.json:8): Poisson
+    document lengths around ``tokens_per_doc`` (at least 8), Zipf(1.3)
+    words over a 50K-word vocabulary, one document per string."""
+    rng = np.random.default_rng(seed)
+    lens = np.maximum(rng.poisson(tokens_per_doc, n_docs), 8).astype(np.int64)
+    ids = rng.zipf(1.3, int(lens.sum())) % 50_000
+    words = np.char.add("w", ids.astype("U6"))
+    docs, pos = [], 0
+    for ln in lens:
+        docs.append(" ".join(words[pos:pos + ln]))
+        pos += ln
+    return docs
+
+
 def load_corpus_lines(path: str) -> tuple[list[str], list[str]]:
     """One document per line (the usual flat-file corpus dump shape)."""
     with open(path, "r", errors="replace") as f:

@@ -41,10 +41,15 @@ def _git_sha() -> str | None:
 
 
 def _device_snapshot() -> dict[str, Any]:
-    """Backend + topology, only when jax is already in the process — the
-    manifest write itself must never be what pulls the jax import chain
-    in (e.g. a run started before the driver's first lazy jax import)."""
+    """Backend + topology, only when the process has already started a
+    jax backend — the manifest write itself must never be what pulls the
+    jax import chain in, nor what claims a chip (a router that only
+    spawns replicas must leave the chip to them)."""
     if "jax" not in sys.modules:
+        return {"backend": None, "devices": None, "device_count": None}
+    from jax._src import xla_bridge
+
+    if not xla_bridge.backends_are_initialized():
         return {"backend": None, "devices": None, "device_count": None}
     try:
         import jax

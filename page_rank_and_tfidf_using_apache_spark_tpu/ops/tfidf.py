@@ -87,15 +87,19 @@ def count_pairs(
     # validity arrays come out directly (no int64 permutation vector, no
     # post-sort gathers), so every aval in the trace stays at the declared
     # 32-bit widths — the tier-2 implicit-promotion gate traces this under
-    # x64 and fails on any 64-bit leak.
+    # x64 and fails on any 64-bit leak.  Unstable: every operand is a key
+    # (or, for the validity flag, a function of one), so any order of equal
+    # keys is the same output — and the TPU compiler builds an unstable
+    # sort in about half the time (21 s vs 46 s for the 19K-doc pipeline,
+    # tests/test_tpu_compile.py).
     if token_valid is not None:
         _, term_s, doc_s, tok_valid_s = jax.lax.sort(
             (~token_valid, term_ids, doc_ids, token_valid),
             num_keys=3,
-            is_stable=True,
+            is_stable=False,
         )
     else:
-        term_s, doc_s = jax.lax.sort((term_ids, doc_ids), num_keys=2, is_stable=True)
+        term_s, doc_s = jax.lax.sort((term_ids, doc_ids), num_keys=2, is_stable=False)
         tok_valid_s = jnp.ones(cap, dtype=bool)
 
     changed = jnp.logical_or(term_s[1:] != term_s[:-1], doc_s[1:] != doc_s[:-1])
@@ -406,4 +410,6 @@ def topk_merge(seg_scores, seg_ids, seg_bases, *, k: int):
         axis=1,
     )
     top, pos = jax.lax.top_k(scores, k)
-    return top, jnp.take_along_axis(ids, pos, axis=1)
+    # Row-wise gather, not take_along_axis: jax 0.9 widens its indices to
+    # int64 under x64.
+    return top, jax.vmap(lambda row, p: row[p])(ids, pos)

@@ -88,9 +88,8 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from page_rank_and_tfidf_using_apache_spark_tpu.parallel.compat import shard_map
 
 from page_rank_and_tfidf_using_apache_spark_tpu import obs
 from page_rank_and_tfidf_using_apache_spark_tpu.dataflow import fixpoint as dataflow
@@ -124,7 +123,18 @@ from page_rank_and_tfidf_using_apache_spark_tpu.utils.config import (
 from page_rank_and_tfidf_using_apache_spark_tpu.utils.metrics import MetricsRecorder, Timer
 
 
-DEFAULT_HBM_BYTES = 8 << 30  # conservative per-chip working budget (v5e: 16G)
+# Stand-in per-device budget for the CPU backend, which has no device
+# memory limit to read; on a TPU the chip's own limit is used.
+CPU_HBM_BYTES = 8 << 30
+
+
+def device_hbm_bytes() -> int:
+    """Per-device memory budget of the default backend: the TPU's
+    ``bytes_limit``, else :data:`CPU_HBM_BYTES`."""
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        return int(dev.memory_stats()["bytes_limit"])
+    return CPU_HBM_BYTES
 
 
 def replicated_state_bytes(
@@ -156,14 +166,12 @@ def auto_select_strategy(
     ``edges`` replicates every node-sized vector on every chip (no memory
     scaling — the round-1 gap for soc-LiveJournal1-sized graphs), so once
     the replicated node state plus this chip's edge slice stops fitting in
-    half the HBM working budget, switch to ``nodes_balanced``: 1/D node
-    state with edge-balanced blocks.  Overridable via the
-    ``PR_TFIDF_HBM_BYTES`` env var (tests use it to force the switch).
+    half the per-device memory (``hbm_bytes``, default
+    :func:`device_hbm_bytes`), switch to ``owned``: 1/D node state with a
+    sparse boundary exchange (``nodes_balanced`` on a non-pow2 mesh).
     """
-    import os
-
     if hbm_bytes is None:
-        hbm_bytes = int(os.environ.get("PR_TFIDF_HBM_BYTES", DEFAULT_HBM_BYTES))
+        hbm_bytes = device_hbm_bytes()
     replicated = replicated_state_bytes(
         graph.n_nodes, graph.n_edges, n_devices, dtype
     )
@@ -766,13 +774,11 @@ def make_sharded_runner(sg: ShardedGraph, cfg: PageRankConfig, mesh: Mesh):
                 ob.pack_boundary(wt, out_idx[0]), axis
             )  # [d*b_pad]: every shard's outgoing boundary values
             lookup = ob.boundary_lookup(wt, btable, wh)
-            tail_contrib = jax.ops.segment_sum(
-                lookup[tsrc[0]] * tw[0], tdst[0],
-                num_segments=block, indices_are_sorted=True,
+            tail_contrib = ops.sorted_segment_sum(
+                lookup[tsrc[0]] * tw[0], tdst[0], block
             )
-            buf = jax.ops.segment_sum(
-                lookup[hsrc[0]] * hw[0], hslot[0],
-                num_segments=h_pad + 2, indices_are_sorted=True,
+            buf = ops.sorted_segment_sum(
+                lookup[hsrc[0]] * hw[0], hslot[0], h_pad + 2
             )
             if redistribute:
                 # head part is replicated: each device contributes 1/d of
@@ -826,15 +832,13 @@ def make_sharded_runner(sg: ShardedGraph, cfg: PageRankConfig, mesh: Mesh):
     def local_reduce(per_edge, dst_row, ip_row, num_segments):
         """Per-device `reduceByKey` over its sorted edge slice: the shared
         scatter-free monotone-diff skeleton under 'cumsum'/'cumsum_mxu',
-        segment_sum otherwise."""
+        the sorted segment sum otherwise."""
         if cfg.spmv_impl == "cumsum":
             return ops.cumsum_diff_spmv(per_edge, ip_row)
         if cfg.spmv_impl == "cumsum_mxu":
             return ops.cumsum_diff_spmv(per_edge, ip_row,
                                         cumsum_fn=ops.cumsum_blocked)
-        return jax.ops.segment_sum(
-            per_edge, dst_row, num_segments=num_segments, indices_are_sorted=True
-        )
+        return ops.sorted_segment_sum(per_edge, dst_row, num_segments)
 
     head_specs: tuple = ()
     if sg.strategy == "edges":
@@ -865,9 +869,7 @@ def make_sharded_runner(sg: ShardedGraph, cfg: PageRankConfig, mesh: Mesh):
         def step(ranks, src, dst, valid, ip, hsrc, hnode, inv, dang, e):
             weighted = ranks * inv
             per_edge = weighted[src[0]] * valid[0]
-            partial = jax.ops.segment_sum(
-                per_edge, dst[0], num_segments=n_pad, indices_are_sorted=True
-            )
+            partial = ops.sorted_segment_sum(per_edge, dst[0], n_pad)
             if has_head:
                 w_ext = jnp.concatenate(
                     [weighted, jnp.zeros(1, weighted.dtype)]
@@ -946,7 +948,11 @@ def make_sharded_runner(sg: ShardedGraph, cfg: PageRankConfig, mesh: Mesh):
     return jax.jit(mapped)
 
 
-def device_put_sharded_graph(sg: ShardedGraph, mesh: Mesh):
+def sharded_graph_layout(sg: ShardedGraph, mesh: Mesh) -> tuple:
+    """The runner's graph operands, in argument order, each paired with
+    its sharding on ``mesh`` — what :func:`device_put_sharded_graph`
+    places, and what a compile for a described (not attached) mesh turns
+    into shapes."""
     axis = mesh.axis_names[0]
     esh = NamedSharding(mesh, P(axis, None))
     if sg.strategy == "owned":
@@ -954,17 +960,17 @@ def device_put_sharded_graph(sg: ShardedGraph, mesh: Mesh):
         tsh = NamedSharding(mesh, P(axis))
         rsh = NamedSharding(mesh, P())
         return (
-            jax.device_put(shard.tail_src_idx, esh),
-            jax.device_put(shard.tail_dst, esh),
-            jax.device_put(shard.tail_w, esh),
-            jax.device_put(shard.head_src_idx, esh),
-            jax.device_put(shard.head_slot, esh),
-            jax.device_put(shard.head_w, esh),
-            jax.device_put(shard.out_idx, esh),
-            jax.device_put(shard.inv_tail, tsh),
-            jax.device_put(shard.dang_tail, tsh),
-            jax.device_put(shard.inv_head, rsh),
-            jax.device_put(shard.dang_head, rsh),
+            (shard.tail_src_idx, esh),
+            (shard.tail_dst, esh),
+            (shard.tail_w, esh),
+            (shard.head_src_idx, esh),
+            (shard.head_slot, esh),
+            (shard.head_w, esh),
+            (shard.out_idx, esh),
+            (shard.inv_tail, tsh),
+            (shard.dang_tail, tsh),
+            (shard.inv_head, rsh),
+            (shard.dang_head, rsh),
         )
     # Node-state vectors follow the strategy: replicated under ``edges`` /
     # ``hybrid`` (the step reads the full vectors), node-sharded under
@@ -972,18 +978,21 @@ def device_put_sharded_graph(sg: ShardedGraph, mesh: Mesh):
     replicated_state = sg.strategy in ("edges", "hybrid")
     vsh = NamedSharding(mesh, P() if replicated_state else P(axis))
     out = [
-        jax.device_put(sg.src, esh),
-        jax.device_put(sg.dst, esh),
-        jax.device_put(sg.valid, esh),
-        jax.device_put(sg.local_indptr, esh),
+        (sg.src, esh),
+        (sg.dst, esh),
+        (sg.valid, esh),
+        (sg.local_indptr, esh),
     ]
     if sg.strategy == "hybrid":
-        out.append(jax.device_put(sg.head_src,
-                                  NamedSharding(mesh, P(axis, None, None))))
-        out.append(jax.device_put(sg.head_node, esh))
-    out.append(jax.device_put(sg.inv_outdeg, vsh))
-    out.append(jax.device_put(sg.dangling, vsh))
+        out.append((sg.head_src, NamedSharding(mesh, P(axis, None, None))))
+        out.append((sg.head_node, esh))
+    out.append((sg.inv_outdeg, vsh))
+    out.append((sg.dangling, vsh))
     return tuple(out)
+
+
+def device_put_sharded_graph(sg: ShardedGraph, mesh: Mesh):
+    return tuple(jax.device_put(a, sh) for a, sh in sharded_graph_layout(sg, mesh))
 
 
 class _ShardedExec:
@@ -1352,6 +1361,17 @@ def run_pagerank_sharded(
             fallbacks=[(None, pull_rebuild)],
         )
     exec_ = exec_box["exec"]  # a rebuild rung may have swapped it
+    # Where the final ranks live, and each mesh device's memory in use
+    # while they do: a layout that lands everything on one chip shows up.
+    placed = pull_view[0] if strategy == "owned" else pull_view
+    metrics.record(
+        event="ranks_placement", strategy=strategy,
+        devices=len(placed.sharding.device_set),
+        bytes_in_use={
+            str(dev.id): (dev.memory_stats() or {}).get("bytes_in_use")
+            for dev in mesh.devices.flat
+        },
+    )
     if strategy == "owned":
         ranks_final = ob.merge_global(
             exec_.sg.owned, ranks_np[0], ranks_np[1]

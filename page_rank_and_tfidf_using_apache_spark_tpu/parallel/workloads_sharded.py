@@ -31,6 +31,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from page_rank_and_tfidf_using_apache_spark_tpu import obs
@@ -49,7 +50,6 @@ from page_rank_and_tfidf_using_apache_spark_tpu.models.pagerank import (
 )
 from page_rank_and_tfidf_using_apache_spark_tpu.ops import boundary as ob
 from page_rank_and_tfidf_using_apache_spark_tpu.parallel import collectives as coll
-from page_rank_and_tfidf_using_apache_spark_tpu.parallel.compat import shard_map
 from page_rank_and_tfidf_using_apache_spark_tpu.parallel.mesh import (
     DATA_AXIS,
     NODES_AXIS,

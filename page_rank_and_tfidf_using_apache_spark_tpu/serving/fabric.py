@@ -97,6 +97,7 @@ from __future__ import annotations
 import argparse
 import collections
 import dataclasses
+import glob
 import hashlib
 import itertools
 import json
@@ -153,6 +154,30 @@ def _peer_knobs() -> "tuple[float, int, float]":
     trip = int(os.environ.get("GRAFT_CACHE_BREAKER_TRIP") or 3)
     probe = float(os.environ.get("GRAFT_CACHE_BREAKER_PROBE_S") or 2.0)
     return deadline, trip, probe
+
+
+def on_tpu_host() -> bool:
+    """Whether replica processes would run on TPU chips here, read from the
+    device nodes (``/dev/accel<N>``, or ``/dev/vfio/<N>`` on v5e) so the
+    router itself never starts JAX and claims a chip; False when replicas
+    run on another platform (``JAX_PLATFORMS`` without tpu)."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return False
+    return bool(glob.glob("/dev/accel[0-9]*") or glob.glob("/dev/vfio/[0-9]*"))
+
+
+def check_chip_budget(replicas: int) -> None:
+    """A replica process claims every chip it can see, so on a TPU host a
+    second replica would fail or hang waiting for one: refuse a fleet of
+    more than one replica process there instead of starting it.  Giving
+    each replica a chip of its own is ROADMAP D8."""
+    if replicas > 1 and on_tpu_host():
+        raise RuntimeError(
+            f"serving fabric: {replicas} replica processes on a TPU host; "
+            "each replica process claims every chip it can see, so only one "
+            "replica can run here"
+        )
 
 
 class FabricExhausted(RuntimeError):
@@ -1225,6 +1250,7 @@ class ServingFabric:
     def start(self) -> "ServingFabric":
         if self._started:
             return self
+        check_chip_budget(self.cfg.replicas)
         obs.emit("fabric_start", replicas=self.cfg.replicas,
                  ring_slots=self.cfg.ring_slots, index_dir=self.index_dir)
         for i in range(self.cfg.replicas):
@@ -1683,6 +1709,9 @@ class ServingFabric:
         so every key owned by a survivor keeps its owner (the churn
         stability property) and only ~1/N of keys move to each newcomer.
         Reuses the exact spawn/handshake machinery of start()/respawn."""
+        with self._lock:
+            live = len(self._handles)
+        check_chip_budget(live + max(0, n))
         added: list[int] = []
         for _ in range(max(0, n)):
             with self._lock:

@@ -15,8 +15,10 @@ pins C++ == numpy on the same inputs.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
+import tempfile
 import threading
 
 import numpy as np
@@ -24,11 +26,24 @@ import numpy as np
 _HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _SRC = os.path.join(_HERE, "native", "fastio.cpp")
 _BUILD_DIR = os.path.join(_HERE, "native", "build")
-_LIB_PATH = os.path.join(_BUILD_DIR, "libfastio.so")
+# Portable flags (no -march=native): the tree may be copied to a host with
+# another CPU.
+_CXX_FLAGS = ("-O3", "-shared", "-fPIC", "-std=c++17")
 
 _lock = threading.Lock()
 _lib: ctypes.CDLL | None = None
 _lib_failed = False
+
+
+def library_path() -> str:
+    """The library built from the current ``fastio.cpp`` with
+    ``_CXX_FLAGS``: its name carries a hash of both, so a library built
+    from other source or flags is never loaded."""
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(_CXX_FLAGS).encode())
+    return os.path.join(_BUILD_DIR, f"libfastio-{h.hexdigest()[:16]}.so")
 
 
 def _load() -> ctypes.CDLL | None:
@@ -43,20 +58,27 @@ def _load() -> ctypes.CDLL | None:
             if not os.path.exists(_SRC):
                 _lib_failed = True
                 return None
-            if not os.path.exists(_LIB_PATH) or os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC):
+            lib_path = library_path()
+            if not os.path.exists(lib_path):
                 os.makedirs(_BUILD_DIR, exist_ok=True)
-                subprocess.run(  # graftlint: disable=blocking-under-lock (build-once guard: the lock is held across the g++ build ON PURPOSE so concurrent loaders wait for one build instead of racing duplicate compilers at the same .so path)
-                    ["g++", "-O3", "-march=native", "-shared", "-fPIC", "-std=c++17",
-                     _SRC, "-o", _LIB_PATH],
-                    check=True,
-                    capture_output=True,
-                )
-            lib = ctypes.CDLL(_LIB_PATH)
+                # build beside the target, then rename: another process
+                # loading the same key never sees a half-written library
+                fd, tmp = tempfile.mkstemp(suffix=".so", dir=_BUILD_DIR)
+                os.close(fd)
+                try:
+                    subprocess.run(  # graftlint: disable=blocking-under-lock (build-once guard: the lock is held across the g++ build ON PURPOSE so concurrent loaders wait for one build instead of racing duplicate compilers)
+                        ["g++", *_CXX_FLAGS, _SRC, "-o", tmp],
+                        check=True,
+                        capture_output=True,
+                    )
+                    os.replace(tmp, lib_path)
+                finally:
+                    if os.path.exists(tmp):
+                        os.unlink(tmp)
+            lib = ctypes.CDLL(lib_path)
             _declare(lib)
             _lib = lib
-        except (OSError, subprocess.CalledProcessError, AttributeError):
-            # AttributeError: a stale prebuilt .so missing a newer symbol —
-            # fall back to numpy rather than crash every ingest call.
+        except (OSError, subprocess.CalledProcessError):
             _lib_failed = True
     return _lib
 

@@ -628,3 +628,27 @@ def test_trace_diff_fabric_nulls_and_absence(tmp_path):
     # a round LOSING its fabric numbers is itself a finding
     rows = td.diff_fabric(new, None, threshold=0.25)
     assert rows and rows[0]["key"] == "fabric.missing"
+
+
+def test_fabric_refuses_a_second_replica_on_a_tpu_host(tmp_path, monkeypatch):
+    """Each replica process claims every chip it sees: on a TPU host the
+    fabric refuses a fleet of more than one replica before spawning any,
+    and scale_up refuses to grow past one."""
+    spawned = []
+    monkeypatch.setattr(fabric, "on_tpu_host", lambda: True)
+    monkeypatch.setattr(fabric.ServingFabric, "_spawn",
+                        lambda self, i, **kw: spawned.append(i))
+    fab = fabric.ServingFabric(str(tmp_path), fabric.FabricConfig(replicas=2))
+    with pytest.raises(RuntimeError, match="only one replica"):
+        fab.start()
+    with pytest.raises(RuntimeError, match="2 replica processes on a TPU host"):
+        fab.scale_up(2)
+    assert spawned == []
+    fabric.check_chip_budget(1)  # one replica process is fine
+
+
+def test_cpu_replicas_are_not_on_a_tpu_host(monkeypatch):
+    """Replicas that run on the CPU have no chip budget."""
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert not fabric.on_tpu_host()
+    fabric.check_chip_budget(4)

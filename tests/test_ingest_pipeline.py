@@ -394,7 +394,7 @@ def test_trace_diff_folds_overlapped_ingest_phases():
 
 def test_wait_site_does_not_retry_iterator_failures():
     """A persistent stage failure whose message carries a transient
-    marker (e.g. XLA 'RESOURCE_EXHAUSTED: out of memory') must NOT be
+    marker (e.g. XLA 'UNAVAILABLE: socket closed') must NOT be
     retried at the ingest_h2d_wait site: the staged iterator is stateful,
     so a re-invoked next() would read _END off the finished Prefetched
     and silently truncate the stream (or skip the failed item inline).
@@ -404,10 +404,10 @@ def test_wait_site_does_not_retry_iterator_failures():
 
         def stage(item):
             if item == 4:
-                raise RuntimeError("RESOURCE_EXHAUSTED: out of memory")
+                raise RuntimeError("UNAVAILABLE: socket closed")
             return item
 
-        with pytest.raises(RuntimeError, match="RESOURCE_EXHAUSTED"):
+        with pytest.raises(RuntimeError, match="UNAVAILABLE"):
             dflow.chunked_ingest(
                 range(8), stage=stage, launch=lambda s: s,
                 drain=drained.append, commit=lambda: None,
@@ -431,12 +431,12 @@ def test_wait_site_recovery_redelivers_after_marker_failure():
     def stage(item):
         if item == 4 and fail["armed"]:
             fail["armed"] = False
-            raise RuntimeError("RESOURCE_EXHAUSTED: out of memory")
+            raise RuntimeError("UNAVAILABLE: socket closed")
         return item
 
     def recover(exc, remaining, where):
         assert where == "stage"
-        assert "RESOURCE_EXHAUSTED" in str(exc)
+        assert "UNAVAILABLE" in str(exc)
         seen.append(sorted(remaining))
         return seen[-1]
 

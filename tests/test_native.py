@@ -190,3 +190,15 @@ def test_from_edges_native_equals_fallback(monkeypatch):
     np.testing.assert_array_equal(g_native.src, g_numpy.src)
     np.testing.assert_array_equal(g_native.dst, g_numpy.dst)
     np.testing.assert_array_equal(g_native.out_degree, g_numpy.out_degree)
+
+
+def test_library_is_keyed_on_source_and_flags(monkeypatch):
+    """A library built from other source or flags is never loaded: the
+    file name carries a hash of both."""
+    from page_rank_and_tfidf_using_apache_spark_tpu.utils import native
+
+    path = native.library_path()
+    assert path == native.library_path()
+    monkeypatch.setattr(native, "_CXX_FLAGS", native._CXX_FLAGS + ("-DX",))
+    assert native.library_path() != path
+    assert "-march=native" not in native._CXX_FLAGS

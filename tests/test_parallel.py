@@ -163,7 +163,7 @@ def test_ring_reduce_scatter_matches_psum_scatter():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from page_rank_and_tfidf_using_apache_spark_tpu.parallel.compat import shard_map
+    from jax import shard_map
 
     from page_rank_and_tfidf_using_apache_spark_tpu.parallel import collectives as coll
 

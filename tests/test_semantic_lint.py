@@ -200,9 +200,7 @@ def _shard_mapped_psum(axis_in_mesh: str, axis_in_code: str):
         import numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from page_rank_and_tfidf_using_apache_spark_tpu.parallel.compat import (
-            shard_map,
-        )
+        from jax import shard_map
 
         mesh = Mesh(np.array(jax.devices("cpu")[:1]), (axis_in_mesh,))
 
@@ -279,9 +277,7 @@ def _shard_divergent(ctrl: str, uniform: bool):
         import numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from page_rank_and_tfidf_using_apache_spark_tpu.parallel.compat import (
-            shard_map,
-        )
+        from jax import shard_map
 
         mesh = Mesh(np.array(jax.devices("cpu")[:1]), ("nodes",))
 

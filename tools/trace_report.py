@@ -519,7 +519,7 @@ def sync_p99(path: str, span_names: frozenset = SYNC_SPAN_NAMES) -> float | None
     """p99 duration (seconds) over the completed sync-flavored spans in a
     trace, or None when the trace holds none.  bench.py feeds a PRIOR
     round's value into the next round's child sync deadline
-    (``max(knob, 3 * p99)``), so the watchdog tracks the tunnel's actually
+    (``max(knob, 3 * p99)``), so the watchdog tracks the syncs' actually
     observed behavior instead of a guess."""
     events, _ = load_events(path)
     secs = sorted(
