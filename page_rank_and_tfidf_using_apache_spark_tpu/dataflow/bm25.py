@@ -28,6 +28,8 @@ import numpy as np
 from page_rank_and_tfidf_using_apache_spark_tpu import obs
 from page_rank_and_tfidf_using_apache_spark_tpu.utils.config import Bm25Config
 
+obs.watch_compiles()
+
 
 @functools.partial(jax.jit, static_argnames=("n_docs", "k1", "b"))
 def bm25_weights(

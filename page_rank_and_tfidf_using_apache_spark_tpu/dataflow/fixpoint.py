@@ -288,7 +288,8 @@ def run_single_chip_fixpoint(
 
     def make_invoke(ops_tuple):
         def invoke(runner, rd):
-            rd, iters, delta = call(runner, ops_tuple, rd)
+            with obs.span(f"{site_prefix}.dispatch"):
+                rd, iters, delta = call(runner, ops_tuple, rd)
             with obs.span(f"{site_prefix}.delta_sync"):
                 delta = float(rx.device_get(
                     delta, site=f"{site_prefix}_delta_sync", metrics=metrics,

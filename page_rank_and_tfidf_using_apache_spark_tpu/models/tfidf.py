@@ -34,6 +34,8 @@ from page_rank_and_tfidf_using_apache_spark_tpu.utils import checkpoint as ckpt
 from page_rank_and_tfidf_using_apache_spark_tpu.utils.config import TfidfConfig, TfMode, ensure_dtype_support
 from page_rank_and_tfidf_using_apache_spark_tpu.utils.metrics import MetricsRecorder, Timer
 
+obs.watch_compiles()
+
 
 @dataclasses.dataclass(frozen=True)
 class TfidfOutput:
@@ -87,7 +89,7 @@ def run_tfidf(
         )
     metrics.record(event="tokenize", docs=corpus.n_docs, tokens=corpus.n_tokens, secs=t_tok.elapsed)
 
-    with Timer() as t_dev, obs.span("tfidf.pipeline"):
+    with obs.span("tfidf.pipeline"):
         result = ops.tfidf_pipeline(
             jnp.asarray(corpus.doc_ids),
             jnp.asarray(corpus.term_ids),
@@ -99,23 +101,20 @@ def run_tfidf(
             l2_normalize=cfg.l2_normalize,
         )
         rx.block_until_ready(result, site="tfidf_batch_sync", metrics=metrics)
-    n_pairs = int(result.n_pairs)
-    metrics.record(
-        event="pipeline", pairs=n_pairs, secs=t_dev.elapsed,
-        tokens_per_sec=corpus.n_tokens / t_dev.elapsed if t_dev.elapsed > 0 else float("inf"),
-    )
-    return TfidfOutput(
-        n_docs=corpus.n_docs,
-        vocab_bits=cfg.vocab_bits,
-        doc=np.asarray(result.doc[:n_pairs]),
-        term=np.asarray(result.term[:n_pairs]),
-        weight=np.asarray(result.weight[:n_pairs]),
-        df=np.asarray(result.df),
-        idf=np.asarray(result.idf),
-        metrics=metrics,
-        count=np.asarray(result.count[:n_pairs]),
-        doc_lengths=np.asarray(corpus.doc_lengths),
-    )
+    with obs.span("tfidf.result_pull"):
+        n_pairs = int(result.n_pairs)
+        return TfidfOutput(
+            n_docs=corpus.n_docs,
+            vocab_bits=cfg.vocab_bits,
+            doc=np.asarray(result.doc[:n_pairs]),
+            term=np.asarray(result.term[:n_pairs]),
+            weight=np.asarray(result.weight[:n_pairs]),
+            df=np.asarray(result.df),
+            idf=np.asarray(result.idf),
+            metrics=metrics,
+            count=np.asarray(result.count[:n_pairs]),
+            doc_lengths=np.asarray(corpus.doc_lengths),
+        )
 
 
 # The fixed-shape capacity policy moved into the dataflow core

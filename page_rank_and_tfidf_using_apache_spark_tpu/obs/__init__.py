@@ -24,6 +24,10 @@ task failure / retry log    ``retry``/``backoff``/``watchdog``/``chaos``
                             /``degraded``/``exhausted`` events
 ==========================  =============================================
 
+JAX's own trace/lower/compile phases become ``jax.*`` spans through
+``obs.watch_compiles()``, which the jax-importing program modules call
+(always on, no knob); the package itself never imports jax.
+
 Env knobs: ``GRAFT_TRACE_DIR`` (default trace directory — a run started
 with no explicit dir writes here; unset = in-memory only) and
 ``GRAFT_LOG_LEVEL`` (stderr log level, utils/metrics.py).  Both declared
@@ -50,6 +54,7 @@ from page_rank_and_tfidf_using_apache_spark_tpu.obs.runtime import (
     span,
     start_run,
     tracer,
+    watch_compiles,
 )
 from page_rank_and_tfidf_using_apache_spark_tpu.obs.trace import SpanTracer
 
@@ -101,4 +106,5 @@ __all__ = [
     "span",
     "start_run",
     "tracer",
+    "watch_compiles",
 ]

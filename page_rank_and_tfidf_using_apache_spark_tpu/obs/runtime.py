@@ -30,7 +30,7 @@ from page_rank_and_tfidf_using_apache_spark_tpu.obs.events import (
     EventBus,
     JsonlSink,
 )
-from page_rank_and_tfidf_using_apache_spark_tpu.obs.trace import SpanTracer
+from page_rank_and_tfidf_using_apache_spark_tpu.obs.trace import CompileWatch, SpanTracer
 
 _BUS = EventBus()
 _TRACER = SpanTracer(_BUS)
@@ -38,6 +38,8 @@ _TRACER = SpanTracer(_BUS)
 _run_lock = threading.Lock()
 _active_run: "Run | None" = None
 _atexit_registered = False
+_watch_lock = threading.Lock()
+_compile_watch: CompileWatch | None = None
 
 
 class Run:
@@ -135,6 +137,20 @@ def emit(kind: str, /, **fields: Any) -> dict[str, Any]:
 def span(name: str, /, *, parent: int | None = None, **attrs: Any):
     """Open a traced span (context manager; see obs/trace.py)."""
     return _TRACER.span(name, parent=parent, **attrs)
+
+
+def watch_compiles() -> CompileWatch:
+    """Publish JAX's trace/lower/compile phases as ``jax.*`` spans on the
+    process tracer (obs/trace.py).  Idempotent: the listener is installed
+    once per process.  Imports jax, so only modules that already import it
+    call this; importing ``obs`` never does."""
+    global _compile_watch
+    with _watch_lock:
+        if _compile_watch is None:
+            watch = CompileWatch(_TRACER)
+            watch.install()
+            _compile_watch = watch
+        return _compile_watch
 
 
 def current_run() -> Run | None:

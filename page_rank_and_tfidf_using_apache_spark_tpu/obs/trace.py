@@ -25,6 +25,14 @@ Design points:
   the thing that drags the jax import chain in.  (Truly jax-free
   processes — the bench parent — do not import this package at all; they
   read trace artifacts through the stdlib-only ``tools/trace_report.py``.)
+- **Compile spans** come from JAX's own clock: ``obs.watch_compiles()``
+  registers one ``jax.monitoring`` listener that turns each trace, lower
+  and backend-compile phase into a ``jax.trace`` / ``jax.lower`` /
+  ``jax.compile`` span, published after the fact through
+  :meth:`SpanTracer.record` and parented to the span the compiling call
+  ran in.  Such a span carries ``begin_wall``/``end_wall`` (JAX's
+  ``time.time()`` stamps) and is not a ``TraceAnnotation``: it closed
+  before it could be opened.
 """
 
 from __future__ import annotations
@@ -99,3 +107,74 @@ class SpanTracer:
                     status=status,
                     attrs=attrs,
                 )
+
+    def record(
+        self, name: str, begin_wall: float, end_wall: float, /, **attrs: Any
+    ) -> int:
+        """Publish one ``span_end`` for a span that already closed, timed
+        by another clock (wall seconds, ``time.time()``).  Its parent is the
+        innermost span open on the calling thread; there is no
+        ``span_begin``, which ``tools/trace_report.py`` accepts."""
+        sid = self._new_id()
+        self._bus.publish(
+            "span_end",
+            span=sid,
+            parent=_current_span.get(),
+            name=name,
+            secs=end_wall - begin_wall,
+            status="ok",
+            attrs=attrs,
+            begin_wall=begin_wall,
+            end_wall=end_wall,
+        )
+        return sid
+
+
+# jax.monitoring time-span events -> span names
+_COMPILE_SPANS = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+}
+# persistent-cache outcome events, fired inside a backend compile
+_CACHE_OUTCOMES = {
+    "/jax/compilation_cache/cache_hits": "hit",
+    "/jax/compilation_cache/cache_misses": "miss",
+}
+
+
+class CompileWatch:
+    """``jax.monitoring`` listeners publishing JAX's compile phases as
+    retroactive spans.  ``jax.compile`` spans carry ``cache``: ``hit`` (the
+    executable came from the persistent cache), ``miss`` (compiled and
+    written to it) or ``none`` (the cache was off, or did not keep it); the
+    outcome event fires inside the compile on the compiling thread, so it
+    is held per thread until that compile's span closes."""
+
+    def __init__(self, tracer: SpanTracer):
+        self._tracer = tracer
+        self._outcome = threading.local()
+
+    def on_event(self, event: str, **_kw: Any) -> None:
+        outcome = _CACHE_OUTCOMES.get(event)
+        if outcome is not None:
+            self._outcome.value = outcome
+
+    def on_span(
+        self, event: str, start_time: float, end_time: float, **kw: Any
+    ) -> None:
+        name = _COMPILE_SPANS.get(event)
+        if name is None:
+            return
+        attrs: dict[str, Any] = {"fun": kw.get("fun_name", "")}
+        if name == "jax.compile":
+            attrs["cache"] = getattr(self._outcome, "value", "none")
+            self._outcome.value = "none"
+        self._tracer.record(name, start_time, end_time, **attrs)
+
+    def install(self) -> None:
+        """Register both listeners with ``jax.monitoring`` (imports jax)."""
+        import jax.monitoring
+
+        jax.monitoring.register_event_listener(self.on_event)
+        jax.monitoring.register_event_time_span_listener(self.on_span)

@@ -536,3 +536,144 @@ def test_bench_refuses_to_run_without_a_tpu():
     assert proc.returncode != 0
     assert proc.stdout.strip() == ""
     assert "no TPU found" in proc.stderr and "'backend': 'cpu'" in proc.stderr
+
+
+# ------------------------------------------- compile and host-phase spans
+
+
+def _span_ends(sink, name):
+    return [e for e in sink.of_kind("span_end") if e["name"] == name]
+
+
+def test_fresh_jit_publishes_compile_spans_inside_its_span(sink):
+    """The first call of a fresh jit publishes jax.trace, jax.lower and
+    jax.compile spans parented to the enclosing span and inside its wall
+    interval; a second call publishes none."""
+    import jax
+    import jax.numpy as jnp
+
+    obs.watch_compiles()
+    step = jax.jit(lambda x: jnp.cos(x) * 3.0 + 1.0)
+    x = jnp.arange(16, dtype=jnp.float32)
+    with obs.span("first") as first:
+        step(x).block_until_ready()
+    begin = next(e for e in sink.of_kind("span_begin") if e["span"] == first)
+    end = _span_ends(sink, "first")[0]
+    compiles = [e for e in sink.of_kind("span_end") if e["name"].startswith("jax.")]
+    assert {"jax.trace", "jax.lower", "jax.compile"} <= {e["name"] for e in compiles}
+    for e in compiles:
+        if e["parent"] != first:
+            continue  # a constant put before the span opened
+        assert begin["wall"] <= e["begin_wall"] <= e["end_wall"] <= end["wall"]
+        assert e["secs"] == pytest.approx(e["end_wall"] - e["begin_wall"])
+    mine = [e for e in compiles if e["parent"] == first]
+    assert {e["name"] for e in mine} == {"jax.trace", "jax.lower", "jax.compile"}
+    assert all(e["attrs"]["fun"] for e in mine)
+    assert {e["attrs"]["cache"] for e in mine if e["name"] == "jax.compile"} <= {
+        "hit", "miss", "none"}
+
+    n = len(sink.events)
+    with obs.span("second") as second:
+        step(x).block_until_ready()
+    assert not [e for e in sink.events[n:] if e.get("name", "").startswith("jax.")]
+    assert _span_ends(sink, "second")[0]["span"] == second
+
+
+def test_watch_compiles_installs_one_listener(sink):
+    """watch_compiles() is idempotent: called again it returns the
+    installed watch, and one compile publishes one jax.compile span."""
+    import jax
+    import jax.numpy as jnp
+
+    watch = obs.watch_compiles()
+    assert obs.watch_compiles() is watch and obs.watch_compiles() is watch
+    x = jnp.ones(5)
+    with obs.span("once") as sid:
+        jax.jit(lambda x: x * 7 - 2)(x).block_until_ready()
+    mine = [e for e in _span_ends(sink, "jax.compile") if e["parent"] == sid]
+    assert [e["attrs"]["fun"] for e in mine] == ["jit(<lambda>)"]
+
+
+def test_record_publishes_a_closed_span(sink):
+    with obs.span("outer") as outer:
+        sid = obs.tracer().record("late", 100.0, 100.25, k=1)
+    (late,) = _span_ends(sink, "late")
+    assert late["span"] == sid != outer and late["parent"] == outer
+    assert late["secs"] == pytest.approx(0.25) and late["attrs"] == {"k": 1}
+    assert (late["begin_wall"], late["end_wall"]) == (100.0, 100.25)
+    assert not [e for e in sink.of_kind("span_begin") if e.get("name") == "late"]
+    # trace_report takes an end without a begin
+    (rec,), _ = _trace_report().pair_spans([late], late["t"])
+    assert rec["name"] == "late" and rec["parent"] == outer
+
+
+OBS_ALONE = """
+import sys, types
+pkg = types.ModuleType("page_rank_and_tfidf_using_apache_spark_tpu")
+pkg.__path__ = [sys.argv[1]]  # the package's own __init__ imports the models
+sys.modules[pkg.__name__] = pkg
+from page_rank_and_tfidf_using_apache_spark_tpu import obs
+assert callable(obs.watch_compiles)
+print("jax" in sys.modules)
+"""
+
+
+def test_importing_obs_alone_leaves_jax_out():
+    proc = subprocess.run(
+        [sys.executable, "-c", OBS_ALONE,
+         str(REPO / "page_rank_and_tfidf_using_apache_spark_tpu")],
+        capture_output=True, text=True, timeout=120, cwd=REPO,
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.strip() == "False"
+
+
+def test_run_pagerank_spans_put_dispatch_and_compile(sink):
+    """A PageRank job puts its graph under pagerank.put_graph and compiles
+    its fresh runner inside pagerank.dispatch."""
+    from page_rank_and_tfidf_using_apache_spark_tpu.io import synthetic_powerlaw
+    from page_rank_and_tfidf_using_apache_spark_tpu.models.pagerank import run_pagerank
+    from page_rank_and_tfidf_using_apache_spark_tpu.utils.config import PageRankConfig
+
+    graph = synthetic_powerlaw(200, 1200, seed=3)
+    with obs.span("job") as job:
+        run_pagerank(graph, PageRankConfig(iterations=4))
+    (put,) = _span_ends(sink, "pagerank.put_graph")
+    (dispatch,) = _span_ends(sink, "pagerank.dispatch")
+    assert put["parent"] == job and dispatch["parent"] != job
+    assert [r for r in sink.of_kind("metric") if r.get("event") == "put_graph"]
+    inside = [e for e in _span_ends(sink, "jax.compile") if e["parent"] == dispatch["span"]]
+    assert len(inside) == 1
+
+
+def test_fixpoint_dispatch_span():
+    """The shared single-chip fixpoint loop dispatches under
+    ``<site_prefix>.dispatch``, one per segment."""
+    from page_rank_and_tfidf_using_apache_spark_tpu.dataflow.hits import run_hits
+    from page_rank_and_tfidf_using_apache_spark_tpu.io import synthetic_powerlaw
+    from page_rank_and_tfidf_using_apache_spark_tpu.utils.config import HitsConfig
+
+    s = obs.MemorySink()
+    obs.bus().attach(s)
+    try:
+        run_hits(synthetic_powerlaw(150, 900, seed=5), HitsConfig(iterations=6, tol=0.0))
+    finally:
+        obs.bus().detach(s)
+    (segment,) = _span_ends(s, "hits.segment")
+    (dispatch,) = _span_ends(s, "hits.dispatch")
+    assert dispatch["parent"] == segment["span"]
+
+
+def test_run_tfidf_pull_span_and_no_pipeline_record(sink):
+    """A batch build pulls its output under tfidf.result_pull; the device
+    span tfidf.pipeline is its only device timer (no ``pipeline`` record)."""
+    from page_rank_and_tfidf_using_apache_spark_tpu.models.tfidf import run_tfidf
+
+    out = run_tfidf([f"alpha beta w{i} w{i % 3}" for i in range(12)],
+                    TfidfConfig(vocab_bits=8))
+    (pull,) = _span_ends(sink, "tfidf.result_pull")
+    (pipe,) = _span_ends(sink, "tfidf.pipeline")
+    assert pull["parent"] == pipe["parent"] and pull["secs"] >= 0
+    assert out.weight.size == out.term.size > 0
+    assert not [r for r in out.metrics.records if r.get("event") == "pipeline"]
+    assert [r["tokens"] for r in out.metrics.records if r.get("event") == "tokenize"] == [48]
