@@ -372,12 +372,15 @@ def _edge_values(dg: DeviceGraph, weighted_ranks: jax.Array) -> jax.Array:
 
 
 # Row width of sorted_segment_sum's in-row scan: the longest run of values
-# summed before the scatter, as a tree of log2(_SEGMENT_ROW) adds.
+# summed as a tree of log2(_SEGMENT_ROW) adds before runs join across rows.
 _SEGMENT_ROW = 512
 
 
 def sorted_segment_sum(
-    values: jax.Array, segment_ids: jax.Array, num_segments: int
+    values: jax.Array,
+    segment_ids: jax.Array,
+    num_segments: int,
+    indptr: jax.Array | None = None,
 ) -> jax.Array:
     """``jax.ops.segment_sum`` over ascending ``segment_ids``, accurate in
     f32 however long a segment runs.
@@ -388,30 +391,26 @@ def sorted_segment_sum(
     4.0e-3 from a float64 reference after 20 iterations, on a v5e and on
     the CPU alike.  Here each ``_SEGMENT_ROW``-wide row of ``values`` is
     first summed per run of equal ids by a segmented Hillis-Steele scan
-    (elementwise shifts, no gather or scatter), and the one scatter-add
-    then takes only each run's total from its last position — one term
-    per row a segment touches."""
-    e = values.shape[0]
-    row = _SEGMENT_ROW
-    if e <= row:
+    (elementwise shifts, no gather or scatter).
+
+    With ``indptr``, the CSR pointers of ``segment_ids`` (``[num_segments
+    + 1]``), and more than one row of values, no scatter is left: the runs
+    are joined across rows and each segment's total is read at its last
+    position (:func:`_sum_runs_at_ends`).  Without them one scatter-add
+    takes each run's total from its last position: one term per row a
+    segment touches, but a scatter over every position."""
+    if indptr is not None and indptr.shape != (num_segments + 1,):
+        raise ValueError(
+            f"indptr has shape {indptr.shape}, want ({num_segments + 1},)"
+        )
+    if values.shape[0] <= _SEGMENT_ROW:  # one row: a scatter of at most 512
         return jax.ops.segment_sum(
             values, segment_ids, num_segments=num_segments,
             indices_are_sorted=True,
         )
-    pad = (-e) % row
-    v = jnp.pad(values, (0, pad)).reshape(-1, row)
-    ids = jnp.pad(segment_ids, (0, pad), mode="edge").reshape(-1, row)
-
-    def shift(x, d, fill):
-        return jnp.pad(x[:, :-d], ((0, 0), (d, 0)), constant_values=fill)
-
-    # after the step at distance d, v[i] sums the (up to 2d) values ending
-    # at i that share its id: ids are sorted, so equal ids d apart bound a
-    # run of equal ids, and unequal ones mean the run starts after i - d
-    d = 1
-    while d < row:
-        v = v + jnp.where(shift(ids, d, -1) == ids, shift(v, d, 0), 0)
-        d *= 2
+    if indptr is not None:
+        return _sum_runs_at_ends(values, segment_ids, indptr)
+    v, ids = _row_scan(values, segment_ids)
     run_end = jnp.concatenate(
         [ids[:, 1:] != ids[:, :-1], jnp.ones((ids.shape[0], 1), bool)], axis=1
     )
@@ -421,11 +420,83 @@ def sorted_segment_sum(
     )
 
 
+def _row_scan(
+    values: jax.Array, segment_ids: jax.Array
+) -> tuple[jax.Array, jax.Array]:
+    """``(v, ids)``: both padded to ``[R, _SEGMENT_ROW]`` rows (the last id
+    repeated, the values 0), ``v`` summed within each row by
+    :func:`_segmented_scan`."""
+    row = _SEGMENT_ROW
+    pad = (-values.shape[0]) % row
+    ids = jnp.pad(segment_ids, (0, pad), mode="edge").reshape(-1, row)
+    return _segmented_scan(jnp.pad(values, (0, pad)).reshape(-1, row), ids), ids
+
+
+def _segmented_scan(v: jax.Array, ids: jax.Array) -> jax.Array:
+    """Inclusive sums along each row of ``v [R, W]`` over runs of equal
+    ``ids`` (ascending along the row): a Hillis-Steele scan of log2(W)
+    elementwise steps, so a run of any length sums as a tree."""
+
+    def shift(x, d, fill):
+        return jnp.pad(x[:, :-d], ((0, 0), (d, 0)), constant_values=fill)
+
+    # after the step at distance d, v[i] sums the (up to 2d) values ending
+    # at i that share its id: ids are sorted, so equal ids d apart bound a
+    # run of equal ids, and unequal ones mean the run starts after i - d
+    d = 1
+    while d < v.shape[1]:
+        v = v + jnp.where(shift(ids, d, -1) == ids, shift(v, d, 0), 0)
+        d *= 2
+    return v
+
+
+# A jit of its own, so that a runner built for each job reuses its trace
+# and lowering instead of lowering the scans' steps again: on a TPU v5e
+# host that cut the runner's lowering from ~360 to ~80 ms a job.
+@jax.jit
+def _sum_runs_at_ends(
+    values: jax.Array, segment_ids: jax.Array, indptr: jax.Array
+) -> jax.Array:
+    """:func:`sorted_segment_sum` through the CSR pointers, scatter-free.
+
+    After :func:`_row_scan`, ``v[r, j]`` sums ``j``'s run within row ``r``
+    up to ``j``.  The rows before ``r`` that hold row ``r``'s first id all
+    end in it, and no other row does: so the carry into row ``r`` sums the
+    run of row tails that ends at row ``r-1``, where row ``r-1`` ends in
+    that id.  The same segmented scan over the ``R`` tails, keyed by each
+    row's last id, sums those runs as a tree, however many rows a hub
+    spans.  Each segment's total is then read at its last position."""
+    v, ids = _row_scan(values, segment_ids)
+    first, last = ids[:, 0], ids[:, -1]
+    through = _segmented_scan(v[None, :, -1], last[None])[0]
+    carry = jnp.concatenate([  # carry[r] into row r
+        jnp.zeros(1, v.dtype),
+        jnp.where(last[:-1] == first[1:], through[:-1], 0),
+    ])
+    # add each row's carry to its first run, then read every segment's
+    # total at its last position: one gather of num_segments positions
+    v = v + jnp.where(ids == first[:, None], carry[:, None], 0)
+    start, end = indptr[:-1], indptr[1:]
+    return jnp.where(end > start, v.ravel()[jnp.maximum(end - 1, 0)], 0)
+
+
 def spmv_segment(dg: DeviceGraph, weighted_ranks: jax.Array, n: int) -> jax.Array:
     """contribs[v] = Σ_{(u,v)∈E} w(u,v)·weighted_ranks[u] via the sorted
     segment sum — the `reduceByKey(add)` of BASELINE.json:5 as one
-    segmented reduction (w ≡ 1 unweighted)."""
-    return sorted_segment_sum(_edge_values(dg, weighted_ranks), dg.dst, n)
+    segmented reduction (w ≡ 1 unweighted).  ``dg.indptr``, where the
+    graph has it, makes that reduction scatter-free (:func:`segment_reduce`)."""
+    return sorted_segment_sum(
+        _edge_values(dg, weighted_ranks), dg.dst, n, indptr=dg.indptr
+    )
+
+
+def segment_reduce(dg: DeviceGraph) -> str:
+    """Which reduction :func:`spmv_segment` lowers for ``dg``: ``"scan"``
+    (scatter-free) where the graph has CSR pointers and more than one row
+    of edges, else ``"scatter"``."""
+    if dg.indptr is None or dg.dst.shape[0] <= _SEGMENT_ROW:
+        return "scatter"
+    return "scan"
 
 
 def spmv_bcoo(dg: DeviceGraph, weighted_ranks: jax.Array, n: int) -> jax.Array:

@@ -127,12 +127,9 @@ def test_cumsum_impl_f32_accuracy(impl):
     assert np.abs(fast.ranks - exact.ranks).sum() < 1e-3
 
 
-@pytest.mark.parametrize("e", [1, 511, 512, 513, 800_000])
-def test_sorted_segment_sum_f32_hub(e):
-    """A hub run of 790K heavy-tailed f32 values sums to within 1e-5
-    relative of float64 (a plain scatter-add drifts ~8e-4 on this data),
-    and every other segment matches too; ragged lengths around the row
-    width included."""
+def _check_sorted_segment_sum(ids, vals, num_segments, scan):
+    """``sorted_segment_sum`` in f32, through the CSR-pointer scan or the
+    scatter of run ends, against a float64 ``np.bincount``."""
     import jax
     import jax.numpy as jnp
 
@@ -140,15 +137,109 @@ def test_sorted_segment_sum_f32_hub(e):
         sorted_segment_sum,
     )
 
+    indptr = None
+    if scan:
+        indptr = jnp.asarray(
+            np.searchsorted(ids, np.arange(num_segments + 1)).astype(np.int32))
+    ref = np.bincount(ids, weights=vals.astype(np.float64), minlength=num_segments)
+    with jax.enable_x64(False):
+        got = np.asarray(sorted_segment_sum(
+            jnp.asarray(vals), jnp.asarray(ids), num_segments, indptr=indptr))
+    assert got.dtype == np.float32
+    np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-12)
+
+
+_HUB_LENGTHS = [1, 511, 512, 513, 800_000]
+
+
+@pytest.mark.parametrize(
+    "e,scan",
+    [pytest.param(e, False, id=str(e)) for e in _HUB_LENGTHS]
+    + [pytest.param(e, True, id=f"scan-{e}") for e in _HUB_LENGTHS],
+)
+def test_sorted_segment_sum_f32_hub(e, scan):
+    """A hub run of 790K heavy-tailed f32 values sums to within 1e-5
+    relative of float64 (a plain scatter-add drifts ~8e-4 on this data),
+    and every other segment matches too; ragged lengths around the row
+    width included, with the CSR pointers (scan) and without (scatter)."""
     rng = np.random.default_rng(e)
     ids = np.sort(rng.integers(0, 1000, e)).astype(np.int32)
     ids[: max(e - 10_000, e // 2)] = 0  # the hub: one long leading run
     vals = rng.lognormal(-16.0, 2.0, e).astype(np.float32)
-    ref = np.bincount(ids, weights=vals.astype(np.float64), minlength=1000)
-    with jax.enable_x64(False):
-        got = np.asarray(sorted_segment_sum(jnp.asarray(vals), jnp.asarray(ids), 1000))
-    assert got.dtype == np.float32
-    np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-12)
+    _check_sorted_segment_sum(ids, vals, 1000, scan)
+
+
+def _runs(*runs):
+    """Ascending segment ids from ``(id, length)`` runs."""
+    return np.concatenate([np.full(k, s, np.int32) for s, k in runs])
+
+
+# Ragged layouts for the carry across the 512-wide rows of the scan.
+_CARRY_CASES = {
+    # id 1 from mid-row 0 to mid-row 4: four row boundaries
+    "run_crosses_four_rows": (_runs((0, 100), (1, 2000), (2, 50)), 3),
+    # row 1 is all id 1, between rows 0 and 2 that also hold id 1
+    "whole_row_between": (_runs((0, 300), (1, 212 + 512 + 100), (2, 7)), 3),
+    # three whole rows of one id, aligned to the rows
+    "aligned_whole_rows": (_runs((5, 3 * 512), (6, 10)), 7),
+    # E = 1024: id 0 ends at row 0's end, id 1 at row 1's (the last edge)
+    "runs_end_at_row_ends": (_runs((0, 512), (1, 512)), 2),
+    "run_ends_at_last_edge": (_runs((0, 200), (1, 824)), 2),
+    # ids 0-2, 5-6 and 9-11 receive nothing; 12 > largest id + 1
+    "empty_start_middle_end": (_runs((3, 700), (4, 3), (7, 600), (8, 1)), 12),
+    "segments_beyond_largest_id": (_runs((0, 1), (1, 1100)), 5000),
+}
+
+
+@pytest.mark.parametrize("scan", [False, True], ids=["scatter", "scan"])
+@pytest.mark.parametrize("case", sorted(_CARRY_CASES))
+def test_sorted_segment_sum_carry_cases(case, scan):
+    """Runs that cross, fill or end at row boundaries, and segments with
+    no values anywhere, sum like float64 on both paths."""
+    ids, num_segments = _CARRY_CASES[case]
+    vals = np.random.default_rng(ids.size).lognormal(
+        -16.0, 2.0, ids.size).astype(np.float32)
+    _check_sorted_segment_sum(ids, vals, num_segments, scan)
+
+
+@pytest.mark.parametrize("nodes,edges", [(300, 1500), (2000, 9000)])
+def test_spmv_segment_lowers_without_scatter(nodes, edges):
+    """With the graph's CSR pointers the segment SpMV lowers to no
+    scatter; without them the scatter of run ends is still there."""
+    import jax
+    import jax.numpy as jnp
+
+    from page_rank_and_tfidf_using_apache_spark_tpu.ops import pagerank as ops
+
+    g = synthetic_powerlaw(nodes, edges, seed=3)
+    dg = ops.put_graph(g, "float32")
+    assert ops.segment_reduce(dg) == "scan"
+    assert ops.segment_reduce(dg._replace(indptr=None)) == "scatter"
+    w = jnp.zeros(g.n_nodes, jnp.float32)
+
+    def hlo(graph):
+        return jax.jit(lambda d, x: ops.spmv_segment(d, x, g.n_nodes)).lower(
+            graph, w).as_text()
+
+    assert "scatter" not in hlo(dg)
+    assert "scatter" in hlo(dg._replace(indptr=None))
+
+
+@pytest.mark.parametrize(
+    "impl,edges,reduce",
+    [("segment", 1500, "scan"), ("segment", 400, "scatter"), ("hybrid", 1500, None)],
+)
+def test_put_graph_record_names_segment_reduce(impl, edges, reduce):
+    """The put_graph record says which reduction the segment SpMV lowered:
+    the scan for the default config, a scatter for a graph of one row of
+    edges, none for a layout impl."""
+    from page_rank_and_tfidf_using_apache_spark_tpu.models.pagerank import run_pagerank
+
+    cfg = PageRankConfig(iterations=2) if impl == "segment" else PageRankConfig(
+        iterations=2, spmv_impl=impl)
+    res = run_pagerank(synthetic_powerlaw(300, edges, seed=3), cfg)
+    (rec,) = [r for r in res.metrics.records if r.get("event") == "put_graph"]
+    assert rec["segment_reduce"] == reduce
 
 
 @pytest.mark.parametrize("n", [0, 1, 5, 512, 513, 128 * 9, 40_001])
