@@ -1,6 +1,7 @@
 from page_rank_and_tfidf_using_apache_spark_tpu.io.graph import (
     Graph,
     from_edges,
+    from_sorted_arcs,
     load_snap,
     parse_snap_text,
     save_ranks,
@@ -19,6 +20,7 @@ from page_rank_and_tfidf_using_apache_spark_tpu.io.text import (
 __all__ = [
     "Graph",
     "from_edges",
+    "from_sorted_arcs",
     "load_snap",
     "parse_snap_text",
     "save_ranks",

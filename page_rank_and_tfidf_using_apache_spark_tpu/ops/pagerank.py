@@ -171,9 +171,10 @@ def build_hybrid_layout(
     in_head[head_ids] = True
 
     # dense head rows: each head node's in-edge run chunked into whole
-    # rows of width w, the last row padded with the sentinel id n.
-    # Vectorized like build_shuffle_layout: per-edge (row, col) from
-    # repeat/offset arithmetic, one fancy-index store for all head edges.
+    # rows of width w, the last row padded with the sentinel id n.  A run
+    # fills a contiguous stretch of the row-major rows, so each is one
+    # slice copy (no per-edge index arrays: at a billion edges those are
+    # several GB each).
     deg = indeg[head_ids] if head_ids.size else np.zeros(0, np.int64)
     rows_per = -(-deg // w)
     r = int(rows_per.sum())
@@ -184,21 +185,18 @@ def build_hybrid_layout(
         np.arange(head_ids.size, dtype=np.int64), rows_per
     ).astype(np.int32)
     if head_ids.size:
-        row_start = np.concatenate([[0], np.cumsum(rows_per)])
-        run_start = np.concatenate([[0], np.cumsum(deg)])
-        offs = np.arange(int(deg.sum()), dtype=np.int64) - np.repeat(
-            run_start[:-1], deg
-        )
-        e_idx = np.repeat(ip[head_ids], deg) + offs
-        rows = np.repeat(row_start[:-1], deg) + offs // w
-        head_src[rows, offs % w] = graph.src[e_idx]
-        if weighted:
-            head_w[rows, offs % w] = graph.weight[e_idx]
+        slot_start = np.concatenate([[0], np.cumsum(rows_per)[:-1]]) * w
+        flat_src = head_src.reshape(-1)
+        flat_w = head_w.reshape(-1) if weighted else None
+        for at, lo, k in zip(slot_start.tolist(), ip[head_ids].tolist(), deg.tolist()):
+            flat_src[at:at + k] = graph.src[lo:lo + k]
+            if weighted:
+                flat_w[at:at + k] = graph.weight[lo:lo + k]
 
     keep = ~in_head[graph.dst]
     tail_src = graph.src[keep].astype(np.int32)
     tail_dst = graph.dst[keep].astype(np.int32)
-    tail_indptr = np.searchsorted(tail_dst, np.arange(n + 1)).astype(np.int32)
+    tail_indptr = np.searchsorted(tail_dst, np.arange(n + 1, dtype=np.int32)).astype(np.int32)
     head_edges = int(graph.n_edges - tail_src.size)
     return HybridHostLayout(
         head_ids=head_ids.astype(np.int32),
@@ -494,7 +492,13 @@ def segment_reduce(dg: DeviceGraph) -> str:
     """Which reduction :func:`spmv_segment` lowers for ``dg``: ``"scan"``
     (scatter-free) where the graph has CSR pointers and more than one row
     of edges, else ``"scatter"``."""
-    if dg.indptr is None or dg.dst.shape[0] <= _SEGMENT_ROW:
+    return segment_reduce_for(dg.dst.shape[0], dg.indptr is not None)
+
+
+def segment_reduce_for(n_values: int, has_indptr: bool) -> str:
+    """Which reduction :func:`sorted_segment_sum` lowers for ``n_values``
+    values, with or without their CSR pointers."""
+    if not has_indptr or n_values <= _SEGMENT_ROW:
         return "scatter"
     return "scan"
 

@@ -8,6 +8,7 @@ from page_rank_and_tfidf_using_apache_spark_tpu.parallel.mesh import (
 )
 from page_rank_and_tfidf_using_apache_spark_tpu.parallel.pagerank_sharded import (
     ShardedGraph,
+    ShardedPageRank,
     auto_select_strategy,
     partition_graph,
     run_pagerank_sharded,
@@ -29,6 +30,7 @@ __all__ = [
     "replicated",
     "sharded_along",
     "ShardedGraph",
+    "ShardedPageRank",
     "auto_select_strategy",
     "partition_graph",
     "run_pagerank_sharded",

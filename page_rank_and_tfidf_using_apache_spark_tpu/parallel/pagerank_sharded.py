@@ -73,7 +73,11 @@ both exist):
 
 Both run the whole iteration loop inside one ``jit`` + ``shard_map``
 program: collectives are compiled into the loop body, so there are zero
-host round-trips between iterations, same as the single-chip path.
+host round-trips between iterations, same as the single-chip path.  Every
+strategy but ``owned`` gives each shard the CSR pointers of its edge slice,
+so its segment sum is the one-chip ``segment`` path's scan, with no
+scatter.  :class:`ShardedPageRank` keeps the partition, the device arrays
+and the compiled runners resident across jobs.
 
 ``spark_exact`` mode is single-chip-only (it exists for parity testing, not
 scale) — requesting it sharded raises.
@@ -311,6 +315,19 @@ def _publish_plan(plan: PartitionPlan, n_devices: int) -> PartitionPlan:
     return plan
 
 
+def _size_bucket(x: int) -> int:
+    """``x`` rounded up to a multiple of ``2**(x.bit_length() - 11)``: at
+    most 1/1024 more.  The replicated layouts pad their per-device widths
+    to it, so graphs a few vertices and arcs apart (two draws of one
+    generator) get one set of shapes, hence one compiled program and one
+    cache entry, and an edge slice of a million or more comes in whole
+    1024-element tiles: the TPU compiler lowers the sharded step into a
+    different program at some slice lengths just short of a tile (PERF.md
+    section 6)."""
+    q = 1 << max(0, int(x).bit_length() - 11)
+    return -(-int(x) // q) * q
+
+
 def plan_partition(
     graph: Graph,
     n_devices: int,
@@ -349,16 +366,16 @@ def plan_partition(
         # sentinel slots plus two ceil remainders.  pad_frac counts ALL
         # dispatched slots (head row slots + tail edge slots) against the
         # real edge count — comparable with the other strategies' gauge.
-        block = max(1, math.ceil(n / d))
+        block = _size_bucket(max(1, math.ceil(n / d)))
         indeg = np.diff(graph.csr_indptr())
         head_ids, w = ops.plan_hybrid_head(
             indeg, e, coverage=head_coverage, row_width=head_row_width
         )
         head_deg = indeg[head_ids]
         rows = int((-(-head_deg // w)).sum()) if head_ids.size else 0
-        rows_dev = math.ceil(rows / d) if rows else 0
+        rows_dev = _size_bucket(math.ceil(rows / d)) if rows else 0
         e_tail = e - int(head_deg.sum())
-        e_dev = max(1, math.ceil(e_tail / d))
+        e_dev = _size_bucket(max(1, math.ceil(e_tail / d)))
         slots = d * (e_dev + rows_dev * w)
         pad_frac = (slots - e) / max(slots, 1)
         return _publish_plan(
@@ -380,8 +397,8 @@ def plan_partition(
         )
 
     if strategy == "edges":
-        block = max(1, math.ceil(n / d))
-        e_dev = max(1, math.ceil(e / d))
+        block = _size_bucket(max(1, math.ceil(n / d)))
+        e_dev = _size_bucket(max(1, math.ceil(e / d)))
         cap = e_dev * d
         pad_frac = (cap - e) / max(cap, 1)
         return _publish_plan(
@@ -464,9 +481,11 @@ class ShardedGraph(NamedTuple):
     # (identity-into-prefix for 'edges'/'nodes'; a relabeling under
     # 'nodes_balanced' where device blocks have unequal node counts)
     local_indptr: np.ndarray  # int32 [D, S+1]: per-device CSR row
-    # pointers into that device's (sorted) edge slice, S = n_pad under
-    # 'edges' / block under node strategies — the monotone-diff pointers
-    # for spmv_impl='cumsum' (host memory cost D*S ints; sharded on device)
+    # pointers into that device's (sorted) edge slice — the tail slice
+    # under 'hybrid' — S = n_pad under 'edges'/'hybrid'/'src*', block
+    # under node strategies: the scatter-free segment sum's pointers, and
+    # the monotone-diff ones of spmv_impl='cumsum' (host memory cost D*S
+    # ints; sharded on device); (D, 1) zeros where not built
     # 'hybrid' only: this device's slice of the dense head rows.  Sentinel
     # source id n_pad reads the zero slot of the step's extended weight
     # vector; all-sentinel padding rows scatter 0.0 into node 0.
@@ -484,17 +503,16 @@ def partition_graph(
     *,
     strategy: str = "edges",
     dtype: str = "float32",
-    need_local_indptr: bool = True,
     head_coverage: float = TUNABLE_DEFAULTS["head_coverage"],
     head_row_width: int = TUNABLE_DEFAULTS["head_row_width"],
     owned_max_head: int = TUNABLE_DEFAULTS["owned_max_head"],
 ) -> ShardedGraph:
     """Partition once on host (the reference partitions on every shuffle).
 
-    ``need_local_indptr=False`` skips the per-device CSR pointer build —
-    only spmv_impl='cumsum' reads it, and under 'edges' it costs D
-    node-sized int32 arrays (a (D, 1) placeholder is stored instead so the
-    runner signature stays fixed).
+    Every strategy but ``owned`` builds per-device CSR pointers
+    (``local_indptr``): each shard's segment sum reduces through them with
+    no scatter, and the 'cumsum' impls difference prefix sums at them.
+    Under 'edges', 'hybrid' and 'src*' they cost D node-sized int32 arrays.
 
     All split boundaries, padded widths and ``pad_frac`` come from
     :func:`plan_partition` — the static plan the tier-3 cost linter
@@ -555,14 +573,14 @@ def partition_graph(
         assert hl.head_src.shape == (rows, w)  # plan IS the layout
         # head rows: remap the single-chip sentinel n -> n_pad (the zero
         # slot of the sharded step's extended weight vector)
-        hsrc_g = hl.head_src.astype(np.int32).copy()
-        hsrc_g[hsrc_g == n] = n_pad
         hnode_g = hl.head_ids[hl.head_row_node].astype(np.int32)
         head_src = np.full((d, max(rows_dev, 1), max(w, 1)), n_pad, np.int32)
         head_node = np.zeros((d, max(rows_dev, 1)), np.int32)
         for i in range(d):
             lo, hi = min(i * rows_dev, rows), min((i + 1) * rows_dev, rows)
-            head_src[i, : hi - lo, :w] = hsrc_g[lo:hi]
+            rows_i = head_src[i, : hi - lo, :w]
+            rows_i[...] = hl.head_src[lo:hi]
+            rows_i[rows_i == n] = n_pad
             head_node[i, : hi - lo] = hnode_g[lo:hi]
         # tail: equal contiguous slices of the tail edge array, 'edges'
         # style (pad src=0 dst=n_pad-1 masked by valid)
@@ -578,11 +596,12 @@ def partition_graph(
         inv[:n] = inv_g
         dangling = np.zeros(n_pad, dtype)
         dangling[:n] = dang_g
+        local_indptr = _slice_indptr(hl.tail_indptr, n_pad, e_dev, d)
         return ShardedGraph(
             strategy, n, n_pad, block,
             src.reshape(d, e_dev), dst.reshape(d, e_dev),
             valid.reshape(d, e_dev), inv, dangling, pad_frac,
-            np.arange(n, dtype=np.int64), np.zeros((d, 1), np.int32),
+            np.arange(n, dtype=np.int64), local_indptr,
             head_src=head_src, head_node=head_node,
         )
 
@@ -616,18 +635,15 @@ def partition_graph(
         inv[:n] = inv_g
         dangling = np.zeros(n_pad, dtype)
         dangling[:n] = dang_g
-        if need_local_indptr:
-            # Per-device CSR pointers over the full padded destination
-            # space: each device's slice is dst-sorted, so its pointers are
-            # one searchsorted over its own slice.
-            local_indptr = np.empty((d, n_pad + 1), np.int32)
-            for i in range(d):
-                k = int(per[i])
-                local_indptr[i] = np.searchsorted(
-                    dst2[i, :k], np.arange(n_pad + 1)
-                ).astype(np.int32)
-        else:
-            local_indptr = np.zeros((d, 1), np.int32)
+        # Per-device CSR pointers over the full padded destination space:
+        # each device's slice is dst-sorted, so its pointers are one
+        # searchsorted over its own slice.
+        local_indptr = np.empty((d, n_pad + 1), np.int32)
+        for i in range(d):
+            k = int(per[i])
+            local_indptr[i] = np.searchsorted(
+                dst2[i, :k], np.arange(n_pad + 1)
+            ).astype(np.int32)
         return ShardedGraph(strategy, n, n_pad, block, src_l, dst2, valid,
                             inv, dangling, pad_frac,
                             np.arange(n, dtype=np.int64), local_indptr)
@@ -645,19 +661,7 @@ def partition_graph(
         dangling = np.zeros(n_pad, dtype)
         dangling[:n] = dang_g
         dst2 = dst.reshape(d, e_dev)
-        if need_local_indptr:
-            # Each device's slice is a contiguous run of the global
-            # dst-sorted edge array, so its CSR pointers are the global
-            # ones shifted by the slice start and clamped to the slice
-            # (padding slots fall outside every segment; they are zero-
-            # valued anyway).  Reuses the cached graph.csr_indptr().
-            g_ip = np.concatenate(
-                [graph.csr_indptr(), np.full(n_pad - n, e, np.int64)]
-            )
-            offsets = (np.arange(d, dtype=np.int64) * e_dev)[:, None]
-            local_indptr = np.clip(g_ip[None, :] - offsets, 0, e_dev).astype(np.int32)
-        else:
-            local_indptr = np.zeros((d, 1), np.int32)
+        local_indptr = _slice_indptr(graph.csr_indptr(), n_pad, e_dev, d)
         return ShardedGraph(strategy, n, n_pad, block,
                             src.reshape(d, e_dev), dst2,
                             valid.reshape(d, e_dev), inv, dangling, pad_frac,
@@ -693,22 +697,32 @@ def partition_graph(
     inv[node_map] = inv_g
     dangling = np.zeros(n_pad, dtype)
     dangling[node_map] = dang_g
-    if need_local_indptr:
-        # Device i's edges are global rows [ebounds[i], ebounds[i+1]) — its
-        # CSR pointers are the global ones for its node range, re-based to
-        # the slice; padding node slots repeat the last pointer (empty
-        # segments) and padding edge slots fall outside every segment.
-        g_ip = graph.csr_indptr()
-        local_indptr = np.empty((d, block + 1), np.int32)
-        for i in range(d):
-            lo_n, hi_n = bounds_nodes[i], bounds_nodes[i + 1]
-            seg = (g_ip[lo_n : hi_n + 1] - ebounds[i]).astype(np.int32)
-            local_indptr[i, : seg.size] = seg
-            local_indptr[i, seg.size :] = seg[-1] if seg.size else 0
-    else:
-        local_indptr = np.zeros((d, 1), np.int32)
+    # Device i's edges are global rows [ebounds[i], ebounds[i+1]) — its CSR
+    # pointers are the global ones for its node range, re-based to the
+    # slice; padding node slots repeat the last pointer (empty segments)
+    # and padding edge slots fall outside every segment.
+    g_ip = graph.csr_indptr()
+    local_indptr = np.empty((d, block + 1), np.int32)
+    for i in range(d):
+        lo_n, hi_n = bounds_nodes[i], bounds_nodes[i + 1]
+        seg = (g_ip[lo_n : hi_n + 1] - ebounds[i]).astype(np.int32)
+        local_indptr[i, : seg.size] = seg
+        local_indptr[i, seg.size :] = seg[-1] if seg.size else 0
     return ShardedGraph(strategy, n, n_pad, block, src, dst_local, valid,
                         inv, dangling, pad_frac, node_map, local_indptr)
+
+
+def _slice_indptr(indptr: np.ndarray, n_pad: int, e_dev: int, d: int) -> np.ndarray:
+    """Per-device CSR pointers of a dst-sorted edge array dealt to ``d``
+    devices in equal contiguous slices of ``e_dev``: each slice's pointers
+    are the global ones (``indptr``, padded to ``n_pad`` nodes) shifted by
+    the slice start and clamped to the slice, so a node whose edges span
+    devices has a segment on each, and padding slots past the last edge
+    fall outside every segment (they are zero-valued anyway)."""
+    n = indptr.shape[0] - 1
+    g_ip = np.concatenate([indptr, np.full(n_pad - n, indptr[-1], np.int64)])
+    offsets = (np.arange(d, dtype=np.int64) * e_dev)[:, None]
+    return np.clip(g_ip[None, :] - offsets, 0, e_dev).astype(np.int32)
 
 
 def _to_padded(sg: ShardedGraph, global_vec: np.ndarray, dtype: str) -> np.ndarray:
@@ -725,7 +739,9 @@ def make_sharded_runner(sg: ShardedGraph, cfg: PageRankConfig, mesh: Mesh):
     """Compile the sharded iteration loop.
 
     Returns ``run(device_arrays...) -> (ranks [n_pad], iters, delta)`` with
-    ranks replicated (``edges``) or node-sharded (``nodes``) on exit.
+    ranks replicated (``edges``) or node-sharded (``nodes``) on exit.  The
+    program is ``jit_sharded_pagerank`` (``jit_sharded_pagerank_owned``
+    under ``owned``) in a device trace.
     """
     if cfg.spark_exact:
         raise NotImplementedError(
@@ -803,7 +819,7 @@ def make_sharded_runner(sg: ShardedGraph, cfg: PageRankConfig, mesh: Mesh):
             )[None]
             return new_tail, new_head, new_dslot, gdelta_prev
 
-        def owned_loop(carry0, *arrays):
+        def sharded_pagerank_owned(carry0, *arrays):
             return dataflow.iterate(
                 lambda c: step(c, *arrays), carry0,
                 iterations=cfg.iterations, tol=cfg.tol,
@@ -813,7 +829,7 @@ def make_sharded_runner(sg: ShardedGraph, cfg: PageRankConfig, mesh: Mesh):
         edge_spec = P(axis, None)
         state_spec = (P(axis), P(), P(axis), P())
         mapped = shard_map(
-            owned_loop,
+            sharded_pagerank_owned,
             mesh=mesh,
             in_specs=(state_spec,
                       edge_spec, edge_spec, edge_spec,  # tail edges
@@ -838,7 +854,8 @@ def make_sharded_runner(sg: ShardedGraph, cfg: PageRankConfig, mesh: Mesh):
         if cfg.spmv_impl == "cumsum_mxu":
             return ops.cumsum_diff_spmv(per_edge, ip_row,
                                         cumsum_fn=ops.cumsum_blocked)
-        return ops.sorted_segment_sum(per_edge, dst_row, num_segments)
+        return ops.sorted_segment_sum(per_edge, dst_row, num_segments,
+                                      indptr=ip_row)
 
     head_specs: tuple = ()
     if sg.strategy == "edges":
@@ -869,7 +886,8 @@ def make_sharded_runner(sg: ShardedGraph, cfg: PageRankConfig, mesh: Mesh):
         def step(ranks, src, dst, valid, ip, hsrc, hnode, inv, dang, e):
             weighted = ranks * inv
             per_edge = weighted[src[0]] * valid[0]
-            partial = ops.sorted_segment_sum(per_edge, dst[0], n_pad)
+            partial = ops.sorted_segment_sum(per_edge, dst[0], n_pad,
+                                             indptr=ip[0])
             if has_head:
                 w_ext = jnp.concatenate(
                     [weighted, jnp.zeros(1, weighted.dtype)]
@@ -927,7 +945,7 @@ def make_sharded_runner(sg: ShardedGraph, cfg: PageRankConfig, mesh: Mesh):
         vec_spec = P(axis)
         local_delta = lambda new, old: coll.psum(jnp.sum(jnp.abs(new - old)), axis)
 
-    def loop(ranks0, *arrays):
+    def sharded_pagerank(ranks0, *arrays):
         # one scan/while skeleton for every fixpoint in the repo: the
         # dataflow core's iterate combinator (dataflow/fixpoint.py), with
         # this strategy's collective delta as the convergence gauge
@@ -938,7 +956,7 @@ def make_sharded_runner(sg: ShardedGraph, cfg: PageRankConfig, mesh: Mesh):
 
     edge_spec = P(axis, None)
     mapped = shard_map(
-        loop,
+        sharded_pagerank,
         mesh=mesh,
         in_specs=(state_spec, edge_spec, edge_spec, edge_spec, edge_spec,
                   *head_specs, vec_spec, vec_spec, vec_spec),
@@ -995,6 +1013,18 @@ def device_put_sharded_graph(sg: ShardedGraph, mesh: Mesh):
     return tuple(jax.device_put(a, sh) for a, sh in sharded_graph_layout(sg, mesh))
 
 
+def shard_segment_reduce(sg: ShardedGraph, spmv_impl: str) -> str | None:
+    """Which reduction each shard's sorted segment sum lowers under this
+    partition: ``"scan"`` (the slice's CSR pointers and more than one
+    512-edge row: no scatter), ``"scatter"``, or None where
+    ``spmv_impl`` reduces by prefix sums.  ``owned`` builds no pointers."""
+    if spmv_impl != "segment":
+        return None
+    if sg.strategy == "owned":
+        return "scatter"
+    return ops.segment_reduce_for(sg.dst.shape[1], has_indptr=True)
+
+
 class _ShardedExec:
     """Everything welded to ONE mesh: the partition, the device-resident
     graph arrays, the state sharding, and the callables run_segments
@@ -1005,18 +1035,18 @@ class _ShardedExec:
                  strategy: str, metrics: MetricsRecorder):
         self.mesh = mesh
         self.d = int(mesh.devices.size)
+        self._runners: dict = {}  # segment config -> jitted runner
         with Timer() as t_part:
             self.sg = partition_graph(
                 graph, self.d, strategy=strategy, dtype=cfg.dtype,
-                need_local_indptr=(
-                    cfg.spmv_impl in ("cumsum", "cumsum_mxu")
-                    and strategy not in ("hybrid", "owned")
-                ),
                 head_coverage=cfg.head_coverage,
                 head_row_width=cfg.head_row_width,
                 owned_max_head=cfg.owned_max_head,
             )
-            self.dev = device_put_sharded_graph(self.sg, mesh)
+            with Timer() as t_put:
+                # fenced so that put_secs times the transfer, not its enqueue
+                self.dev = jax.block_until_ready(  # graftlint: disable=unguarded-host-sync (a fence on this build's own host-to-device put, no computation; the first guarded step syncs anyway)
+                    device_put_sharded_graph(self.sg, mesh))
         # the static per-step exchange footprint: ICI bytes each device
         # sends per iteration under this partition (the sublinearity gauge
         # the MULTICHIP scale sweep + trace_diff comm gate consume)
@@ -1037,7 +1067,9 @@ class _ShardedExec:
                 if self.sg.strategy == "owned" else self.sg.src.shape[1]
             ),
             pad_frac=round(self.sg.pad_frac, 4), secs=t_part.elapsed,
+            put_secs=t_put.elapsed,
             comm_bytes_per_step=self.comm_bytes_per_step,
+            segment_reduce=shard_segment_reduce(self.sg, cfg.spmv_impl),
         )
         axis = mesh.axis_names[0]
         self._cfg = cfg
@@ -1075,7 +1107,17 @@ class _ShardedExec:
         )
 
     def make_runner(self, seg_cfg: PageRankConfig):
-        return make_sharded_runner(self.sg, seg_cfg, self.mesh)
+        """The jitted runner of one segment config, built once: a later
+        job reuses it, and with it its compiled program."""
+        if seg_cfg not in self._runners:
+            self._runners[seg_cfg] = make_sharded_runner(self.sg, seg_cfg, self.mesh)
+        return self._runners[seg_cfg]
+
+    def args(self, rd) -> tuple:
+        """The runner's arguments around the rank state ``rd``."""
+        if self.sg.strategy == "owned":
+            return (rd, *self.dev, *self.e_vec)
+        return (rd, *self.dev, self.e_vec)
 
     def invoke(self, runner, rd):
         if self.sg.strategy == "owned":
@@ -1085,7 +1127,8 @@ class _ShardedExec:
             # re-dispatch into the consumed carry (models/pagerank.py's
             # pagerank_delta_sync discipline).
             owned_runner = runner
-            rd, iters, delta = owned_runner(rd, *self.dev, *self.e_vec)
+            with obs.span("pagerank.dispatch"):
+                rd, iters, delta = owned_runner(rd, *self.dev, *self.e_vec)
             with obs.span("pagerank.delta_sync"):
                 delta = float(rx.device_get(
                     delta, site="pagerank_delta_sync",
@@ -1093,8 +1136,11 @@ class _ShardedExec:
                     checkpoint_dir=self._cfg.checkpoint_dir,
                 ))
             return rd, iters, delta
-        rd, iters, delta = runner(rd, *self.dev, self.e_vec)
-        delta = float(delta)  # scalar fetch is the only reliable device sync
+        # a fresh runner traces, lowers and compiles inside the dispatch span
+        with obs.span("pagerank.dispatch"):
+            rd, iters, delta = runner(*self.args(rd))
+        with obs.span("pagerank.delta_sync"):
+            delta = float(delta)  # scalar fetch is the only reliable device sync
         return rd, iters, delta
 
     def put_ranks(self, ranks_g: np.ndarray):
@@ -1223,6 +1269,218 @@ def _make_elastic_rebuild(graph: Graph, cfg: PageRankConfig, strategy: str,
     return rebuild
 
 
+class ShardedPageRank:
+    """A graph partitioned and resident on a mesh, with its runners: built
+    once, then any number of PageRank jobs run on it.
+
+    Building resolves ``strategy="auto"`` (:func:`auto_select_strategy`,
+    by per-chip memory and degree shape), partitions the graph on the
+    host, puts its arrays on the mesh and publishes the ``partition``
+    record.  :meth:`run` is one job: start ranks put, ``cfg.iterations``
+    steps through ``driver.run_segments``, ranks pulled and un-padded.  A
+    later job partitions, puts and compiles nothing: the runner of each
+    segment length is built once (:meth:`compile` builds them ahead of the
+    first job).  A device lost in a job rebuilds the layout over the
+    survivors (the elastic rung), and later jobs run on that mesh."""
+
+    def __init__(
+        self,
+        graph: Graph,
+        cfg: PageRankConfig,
+        *,
+        n_devices: int | None = None,
+        mesh: Mesh | None = None,
+        strategy: str = "edges",
+        metrics: MetricsRecorder | None = None,
+    ):
+        ensure_dtype_support(cfg.dtype)
+        self.metrics = metrics or MetricsRecorder()
+        if mesh is None:
+            mesh = make_mesh(n_devices, NODES_AXIS)
+        d = mesh.devices.size
+        self.graph = graph
+        if strategy == "auto" and graph.n_nodes:
+            strategy = auto_select_strategy(
+                graph, d, dtype=cfg.dtype,
+                head_coverage=cfg.head_coverage,
+                head_row_width=cfg.head_row_width,
+            )
+            self.metrics.record(event="auto_strategy", chosen=strategy, devices=d)
+        self.strategy = strategy
+        self.cfg = driver.resolve_personalize(graph, cfg)
+        # the elastic rungs swap in the exec rebuilt over the survivors
+        self._box = {"exec": (
+            _ShardedExec(graph, self.cfg, mesh, strategy, self.metrics)
+            if graph.n_nodes else None
+        )}
+
+    @property
+    def exec(self) -> _ShardedExec:
+        return self._box["exec"]
+
+    def compile(self) -> None:
+        """Compile the programs of a job from the first iteration, without
+        running one: each segment's runner is lowered and compiled against
+        start ranks put on the mesh."""
+        cfg = self.cfg
+        if self.exec is None:
+            return
+        # the segment lengths driver.run_segments runs a fresh job in
+        seg = (cfg.checkpoint_every
+               if cfg.checkpoint_every > 0 and cfg.tol == 0.0 else cfg.iterations)
+        lengths, done = set(), 0
+        while done < cfg.iterations:
+            lengths.add(min(seg, cfg.iterations - done))
+            done += min(seg, cfg.iterations - done)
+        rd = self.exec.put_ranks(ops.init_ranks(self.exec.sg.n, cfg))
+        for todo in lengths:
+            seg_cfg = dataclasses.replace(
+                cfg, iterations=todo, checkpoint_every=0, checkpoint_dir=None
+            )
+            self.exec.make_runner(seg_cfg).lower(*self.exec.args(rd)).compile()
+
+    def run(self, *, resume: bool = False) -> PageRankResult:
+        """One job on the resident graph; its records go to ``metrics``."""
+        cfg, metrics, strategy = self.cfg, self.metrics, self.strategy
+        if self.exec is None:
+            return PageRankResult(np.zeros(0, cfg.dtype), 0, 0.0, metrics)
+        with obs.span("pagerank.sharded_job", strategy=strategy):
+            result = self._run(resume)
+        exec_ = self.exec
+        metrics.record(event="sharded_job", strategy=strategy,
+                       iterations=result.iterations, devices=exec_.d,
+                       comm_bytes_per_step=exec_.comm_bytes_per_step)
+        return result
+
+    def _run(self, resume: bool) -> PageRankResult:
+        cfg, metrics, strategy = self.cfg, self.metrics, self.strategy
+        graph, exec_box = self.graph, self._box
+        exec_ = exec_box["exec"]
+        mesh, d = exec_.mesh, exec_.d
+        ranks_g = ops.init_ranks(exec_.sg.n, cfg)
+        start_iter = (
+            driver.resume_from_checkpoint(cfg, metrics, ranks_g, n=exec_.sg.n)
+            if resume else 0
+        )
+        ranks_dev = exec_.put_ranks(ranks_g)
+
+        # No make_cpu_invoke here: the compiled program is welded to the mesh
+        # (collectives over its axis), so there is no single-device re-lowering
+        # of the SAME program to degrade to.  The elastic rung is the sharded
+        # degradation path: rebuild over survivors down to a 1-device mesh
+        # (which the CPU backend can host when the accelerator pool is gone).
+        ranks_dev, done, last_delta = driver.run_segments(
+            cfg, metrics, ranks_dev, start_iter,
+            make_runner=exec_.make_runner,
+            invoke=exec_.invoke,
+            extract_np=exec_.extract_np,
+            extra_metrics={"devices": d},
+            elastic_rebuild=_make_elastic_rebuild(
+                graph, cfg, strategy, metrics, exec_box
+            ),
+        )
+        # Device loss FIRST surfacing at the result pull (no segment dispatch
+        # left to catch it) used to exhaust the ladder; this rung routes the
+        # pull through the same elastic shrink: salvage the newest checkpoint
+        # (the live buffers died with the device), rebuild over the survivors,
+        # re-run the uncommitted iterations there, and pull from the rebuilt
+        # mesh.  The rung swaps exec_box so the node_map below matches the
+        # layout the returned padded ranks were produced in.
+        def pull_rebuild(exc):
+            if not elastic.enabled() or not elastic.is_device_loss(exc):
+                raise exc
+            idx = elastic.device_index(exc)
+            if idx is not None:
+                elastic.health().mark_lost(idx)
+            old = exec_box["exec"]
+            at_iter, ranks_g = 0, ops.init_ranks(old.sg.n, cfg)
+            if cfg.checkpoint_dir:
+                latest = ckpt.latest_checkpoint(cfg.checkpoint_dir)
+                if latest is not None:
+                    step, arrays, _ = ckpt.load_checkpoint(latest, cfg.config_hash())
+                    at_iter, ranks_g = int(step), arrays["ranks"]
+            devices = list(old.mesh.devices.flat)
+            axis = old.mesh.axis_names[0]
+            todo = done - at_iter
+            seg_cfg = dataclasses.replace(
+                cfg, iterations=todo, checkpoint_every=0, checkpoint_dir=None
+            )
+            # loop for the same reason as the segment rung: a second loss
+            # during the re-run of the uncommitted span re-enters the ladder
+            # (re-plan from the shrunk mesh) instead of exhausting
+            while True:
+                plan = elastic.plan_shrink(devices)
+                if plan is None:
+                    raise exc
+                with elastic.publish_shrink(
+                    "pagerank_result_pull", plan, exc, metrics
+                ):
+                    new_mesh = rebuild_mesh(plan.devices, axis)
+                    new = _ShardedExec(graph, cfg, new_mesh, strategy, metrics)
+                    rd2 = new.put_ranks(ranks_g)
+                if todo <= 0:
+                    break
+                try:
+                    rd2, _, _ = rx.attempt_once(
+                        lambda n=new, r=rd2: n.invoke(n.make_runner(seg_cfg), r),
+                        site="pagerank_elastic_rerun",
+                    )
+                    break
+                except Exception as exc2:  # noqa: BLE001 — re-entry filter below
+                    lost = elastic.unwrap_device_loss(exc2)
+                    if lost is None:
+                        raise
+                    idx2 = elastic.device_index(lost)
+                    if idx2 is not None:
+                        elastic.health().mark_lost(idx2)
+                    exc = lost
+                    devices = list(new_mesh.devices.flat)
+            exec_box["exec"] = new
+            # same site: chaos's device_lost is gated on the health registry,
+            # so the acknowledged loss cannot re-fire here
+            with obs.span("pagerank.result_pull_rebuilt"):
+                return rx.device_get(
+                    (rd2[0], rd2[1]) if strategy == "owned" else rd2,
+                    site="pagerank_result_pull", metrics=metrics,
+                    checkpoint_dir=cfg.checkpoint_dir,
+                )
+
+        with obs.span("pagerank.result_pull"):
+            # owned state is a (tail, head, dslot, gdelta) carry: only the
+            # two rank components cross D2H — the delta slots are scratch
+            pull_view = (
+                (ranks_dev[0], ranks_dev[1]) if strategy == "owned"
+                else ranks_dev
+            )
+            ranks_np = rx.device_get(
+                pull_view, site="pagerank_result_pull", metrics=metrics,
+                checkpoint_dir=cfg.checkpoint_dir,
+                fallbacks=[(None, pull_rebuild)],
+            )
+        exec_ = exec_box["exec"]  # a rebuild rung may have swapped it
+        # Where the final ranks live, and each mesh device's memory in use
+        # while they do: a layout that lands everything on one chip shows up.
+        placed = pull_view[0] if strategy == "owned" else pull_view
+        metrics.record(
+            event="ranks_placement", strategy=strategy,
+            devices=len(placed.sharding.device_set),
+            bytes_in_use={
+                str(dev.id): (dev.memory_stats() or {}).get("bytes_in_use")
+                for dev in mesh.devices.flat
+            },
+        )
+        if strategy == "owned":
+            ranks_final = ob.merge_global(
+                exec_.sg.owned, ranks_np[0], ranks_np[1]
+            )
+        else:
+            ranks_final = ranks_np[exec_.sg.node_map]
+        return PageRankResult(
+            ranks=ranks_final, iterations=done,
+            l1_delta=last_delta, metrics=metrics,
+        )
+
+
 def run_pagerank_sharded(
     graph: Graph,
     cfg: PageRankConfig,
@@ -1236,149 +1494,13 @@ def run_pagerank_sharded(
     """Sharded counterpart of models.pagerank.run_pagerank — same semantics
     flags, same checkpoint segments, ranks bit-comparable across device
     counts up to float reduction order (chip-count invariance is pinned by
-    tests/test_parallel.py).
+    tests/test_parallel.py): a :class:`ShardedPageRank` built for one job.
 
     Device loss no longer aborts the run: the elastic rung (resilience/
     elastic.py) shrinks the mesh onto the surviving devices, repartitions,
     and resumes — falling through to ``ResilienceExhausted`` + checkpoint
     only when nothing survives or ``GRAFT_ELASTIC=0``."""
-    ensure_dtype_support(cfg.dtype)
-    metrics = metrics or MetricsRecorder()
-    if mesh is None:
-        mesh = make_mesh(n_devices, NODES_AXIS)
-    d = mesh.devices.size
-    if graph.n_nodes == 0:
-        return PageRankResult(np.zeros(0, cfg.dtype), 0, 0.0, metrics)
-    if strategy == "auto":
-        strategy = auto_select_strategy(
-            graph, d, dtype=cfg.dtype,
-            head_coverage=cfg.head_coverage,
-            head_row_width=cfg.head_row_width,
-        )
-        metrics.record(event="auto_strategy", chosen=strategy, devices=d)
-    cfg = driver.resolve_personalize(graph, cfg)
-
-    exec_ = _ShardedExec(graph, cfg, mesh, strategy, metrics)
-    ranks_g = ops.init_ranks(exec_.sg.n, cfg)
-    start_iter = (
-        driver.resume_from_checkpoint(cfg, metrics, ranks_g, n=exec_.sg.n)
-        if resume else 0
-    )
-    ranks_dev = exec_.put_ranks(ranks_g)
-
-    # No make_cpu_invoke here: the compiled program is welded to the mesh
-    # (collectives over its axis), so there is no single-device re-lowering
-    # of the SAME program to degrade to.  The elastic rung is the sharded
-    # degradation path: rebuild over survivors down to a 1-device mesh
-    # (which the CPU backend can host when the accelerator pool is gone).
-    exec_box = {"exec": exec_}
-    ranks_dev, done, last_delta = driver.run_segments(
-        cfg, metrics, ranks_dev, start_iter,
-        make_runner=exec_.make_runner,
-        invoke=exec_.invoke,
-        extract_np=exec_.extract_np,
-        extra_metrics={"devices": d},
-        elastic_rebuild=_make_elastic_rebuild(
-            graph, cfg, strategy, metrics, exec_box
-        ),
-    )
-    # Device loss FIRST surfacing at the result pull (no segment dispatch
-    # left to catch it) used to exhaust the ladder; this rung routes the
-    # pull through the same elastic shrink: salvage the newest checkpoint
-    # (the live buffers died with the device), rebuild over the survivors,
-    # re-run the uncommitted iterations there, and pull from the rebuilt
-    # mesh.  The rung swaps exec_box so the node_map below matches the
-    # layout the returned padded ranks were produced in.
-    def pull_rebuild(exc):
-        if not elastic.enabled() or not elastic.is_device_loss(exc):
-            raise exc
-        idx = elastic.device_index(exc)
-        if idx is not None:
-            elastic.health().mark_lost(idx)
-        old = exec_box["exec"]
-        at_iter, ranks_g = 0, ops.init_ranks(old.sg.n, cfg)
-        if cfg.checkpoint_dir:
-            latest = ckpt.latest_checkpoint(cfg.checkpoint_dir)
-            if latest is not None:
-                step, arrays, _ = ckpt.load_checkpoint(latest, cfg.config_hash())
-                at_iter, ranks_g = int(step), arrays["ranks"]
-        devices = list(old.mesh.devices.flat)
-        axis = old.mesh.axis_names[0]
-        todo = done - at_iter
-        seg_cfg = dataclasses.replace(
-            cfg, iterations=todo, checkpoint_every=0, checkpoint_dir=None
-        )
-        # loop for the same reason as the segment rung: a second loss
-        # during the re-run of the uncommitted span re-enters the ladder
-        # (re-plan from the shrunk mesh) instead of exhausting
-        while True:
-            plan = elastic.plan_shrink(devices)
-            if plan is None:
-                raise exc
-            with elastic.publish_shrink(
-                "pagerank_result_pull", plan, exc, metrics
-            ):
-                new_mesh = rebuild_mesh(plan.devices, axis)
-                new = _ShardedExec(graph, cfg, new_mesh, strategy, metrics)
-                rd2 = new.put_ranks(ranks_g)
-            if todo <= 0:
-                break
-            try:
-                rd2, _, _ = rx.attempt_once(
-                    lambda n=new, r=rd2: n.invoke(n.make_runner(seg_cfg), r),
-                    site="pagerank_elastic_rerun",
-                )
-                break
-            except Exception as exc2:  # noqa: BLE001 — re-entry filter below
-                lost = elastic.unwrap_device_loss(exc2)
-                if lost is None:
-                    raise
-                idx2 = elastic.device_index(lost)
-                if idx2 is not None:
-                    elastic.health().mark_lost(idx2)
-                exc = lost
-                devices = list(new_mesh.devices.flat)
-        exec_box["exec"] = new
-        # same site: chaos's device_lost is gated on the health registry,
-        # so the acknowledged loss cannot re-fire here
-        with obs.span("pagerank.result_pull_rebuilt"):
-            return rx.device_get(
-                (rd2[0], rd2[1]) if strategy == "owned" else rd2,
-                site="pagerank_result_pull", metrics=metrics,
-                checkpoint_dir=cfg.checkpoint_dir,
-            )
-
-    with obs.span("pagerank.result_pull"):
-        # owned state is a (tail, head, dslot, gdelta) carry: only the
-        # two rank components cross D2H — the delta slots are scratch
-        pull_view = (
-            (ranks_dev[0], ranks_dev[1]) if strategy == "owned"
-            else ranks_dev
-        )
-        ranks_np = rx.device_get(
-            pull_view, site="pagerank_result_pull", metrics=metrics,
-            checkpoint_dir=cfg.checkpoint_dir,
-            fallbacks=[(None, pull_rebuild)],
-        )
-    exec_ = exec_box["exec"]  # a rebuild rung may have swapped it
-    # Where the final ranks live, and each mesh device's memory in use
-    # while they do: a layout that lands everything on one chip shows up.
-    placed = pull_view[0] if strategy == "owned" else pull_view
-    metrics.record(
-        event="ranks_placement", strategy=strategy,
-        devices=len(placed.sharding.device_set),
-        bytes_in_use={
-            str(dev.id): (dev.memory_stats() or {}).get("bytes_in_use")
-            for dev in mesh.devices.flat
-        },
-    )
-    if strategy == "owned":
-        ranks_final = ob.merge_global(
-            exec_.sg.owned, ranks_np[0], ranks_np[1]
-        )
-    else:
-        ranks_final = ranks_np[exec_.sg.node_map]
-    return PageRankResult(
-        ranks=ranks_final, iterations=done,
-        l1_delta=last_delta, metrics=metrics,
-    )
+    return ShardedPageRank(
+        graph, cfg, n_devices=n_devices, mesh=mesh, strategy=strategy,
+        metrics=metrics,
+    ).run(resume=resume)

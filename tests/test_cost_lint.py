@@ -292,8 +292,7 @@ def test_plan_is_what_partition_graph_materializes():
                      "hybrid"):
         for d in (1, 2, 4):
             plan = ps.plan_partition(graph, d, strategy=strategy)
-            sg = ps.partition_graph(graph, d, strategy=strategy,
-                                    need_local_indptr=False)
+            sg = ps.partition_graph(graph, d, strategy=strategy)
             assert sg.pad_frac == plan.pad_frac, (strategy, d)
             assert sg.n_pad == plan.n_pad and sg.block == plan.block
             assert sg.src.shape == (d, plan.e_dev)

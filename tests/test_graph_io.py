@@ -7,6 +7,7 @@ import pytest
 
 from page_rank_and_tfidf_using_apache_spark_tpu.io import (
     from_edges,
+    from_sorted_arcs,
     load_snap,
     parse_snap_text,
     save_ranks,
@@ -80,3 +81,43 @@ def test_synthetic_powerlaw_shape():
     # power-law: max in-degree far above mean
     indeg = np.bincount(g.dst, minlength=g.n_nodes)
     assert indeg.max() > 10 * indeg.mean()
+
+
+@pytest.mark.parametrize("chunk", [1 << 24, 7])
+def test_from_sorted_arcs_is_from_edges(monkeypatch, chunk):
+    """Arcs already sorted and unique build the Graph ``from_edges`` does,
+    out-degrees counted in chunks (7 arcs: runs cross the chunk bounds)."""
+    from page_rank_and_tfidf_using_apache_spark_tpu.io import graph as graph_io
+
+    monkeypatch.setattr(graph_io, "_ARC_CHUNK", chunk)
+    want = synthetic_powerlaw(300, 2400, seed=11)
+    got = from_sorted_arcs(want.src, want.dst, want.n_nodes)
+    assert got.n_nodes == want.n_nodes and got.src is want.src and got.dst is want.dst
+    np.testing.assert_array_equal(got.out_degree, want.out_degree)
+    assert got.out_degree.dtype == np.int32
+    np.testing.assert_array_equal(got.node_ids, np.arange(want.n_nodes))
+    empty = from_sorted_arcs(np.zeros(0, np.int32), np.zeros(0, np.int32), 3)
+    assert empty.n_edges == 0 and list(empty.out_degree) == [0, 0, 0]
+
+
+@pytest.mark.parametrize("fault", ["unsorted", "duplicate", "src_order", "range", "int64"])
+def test_from_sorted_arcs_refuses_broken_arcs(monkeypatch, fault):
+    from page_rank_and_tfidf_using_apache_spark_tpu.io import graph as graph_io
+
+    monkeypatch.setattr(graph_io, "_ARC_CHUNK", 4)  # the fault sits across a chunk bound
+    src = np.array([1, 2, 0, 2, 0, 1, 3, 0, 1], np.int32)
+    dst = np.array([0, 0, 1, 1, 2, 2, 2, 3, 3], np.int32)
+    assert list(from_sorted_arcs(src, dst, 4).out_degree) == [3, 3, 2, 1]
+    if fault == "unsorted":
+        dst[4] = 0
+    elif fault == "duplicate":
+        src[4] = src[3] = 2
+        dst[4] = 1
+    elif fault == "src_order":
+        src[3], src[4], dst[4] = 2, 0, 1
+    elif fault == "range":
+        src[5] = 4
+    else:
+        src = src.astype(np.int64)
+    with pytest.raises(ValueError):
+        from_sorted_arcs(src, dst, 4)
