@@ -87,6 +87,9 @@ def run_pagerank(
         metrics.record(event="put_graph", spmv_impl=cfg.spmv_impl,
                        segment_reduce=(ops.segment_reduce(dg)
                                        if cfg.spmv_impl == "segment" else None),
+                       gather_chunks=(ops.gather_chunks(dg.src.shape)
+                                      if cfg.spmv_impl in ops.EDGE_GATHER_IMPLS
+                                      else None),
                        preprocess_secs=t_put.elapsed)
         e = jax.device_put(ops.restart_vector(n, cfg))
         ranks = np.asarray(ops.init_ranks(n, cfg))

@@ -29,6 +29,7 @@ Semantics flags (SURVEY.md §3.1 dangling-node caveat):
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple
 
 import jax
@@ -359,11 +360,60 @@ def init_ranks(n: int, cfg: PageRankConfig) -> np.ndarray:
     return np.full(n, 1.0 / n, dtype=cfg.dtype)
 
 
+# Most indices one gather reads at once.  XLA stages a gather's table in a
+# TPU's VMEM only while the gather reads up to ~100 M indices; beyond that
+# the table stays in HBM.  On a TPU v5e, 131 M random indices into a 17 M
+# entry f32 table cost 14.6 ns an element in one gather, 8.6 in chunks of
+# 16 M, 7.2 in chunks of 8 M and of 4 M, 6.7 in chunks of 2 M; 5.1 M
+# indices into a 0.9 M-entry table cost 6.7 whole.  The cap sits where the
+# rate first levels off, above the gathers that already run at the chunked
+# rate whole.
+GATHER_CAP = 1 << 23
+
+
+def gather_chunks(shape: tuple[int, ...]) -> int:
+    """How many chunks of whole leading rows :func:`gather_rows` splits an
+    index array of ``shape`` into: 1 up to :data:`GATHER_CAP` indices, else
+    as few as keep each chunk within the cap (or one row, where a row alone
+    exceeds it)."""
+    row = math.prod(shape[1:])
+    if math.prod(shape) <= GATHER_CAP:
+        return 1
+    return -(-shape[0] // max(1, GATHER_CAP // row))
+
+
+def gather_rows(table: jax.Array, idx: jax.Array) -> jax.Array:
+    """``table[idx]`` for a 1-D table, split along ``idx``'s leading axis
+    into :func:`gather_chunks` chunks under one loop where ``idx`` holds
+    more than :data:`GATHER_CAP` indices, so that XLA can keep the table in
+    VMEM.  The chunks are equal; the last one ends at the leading axis's
+    end and may overlap its predecessor, which it overwrites with the same
+    values.  At or below the cap this is exactly ``table[idx]``, with no
+    loop."""
+    k = gather_chunks(idx.shape)
+    if k == 1:
+        return table[idx]
+    lead = idx.shape[0]
+    size = -(-lead // k)
+    rest = (0,) * (idx.ndim - 1)
+
+    def chunk(i, out):
+        start = jnp.minimum(i * size, lead - size)
+        part = jax.lax.dynamic_slice(idx, (start, *rest), (size, *idx.shape[1:]))
+        return jax.lax.dynamic_update_slice(out, table[part], (start, *rest))
+
+    return jax.lax.fori_loop(0, k, chunk, jnp.zeros(idx.shape, table.dtype))
+
+
+# The raw-edge impls whose SpMV gathers through :func:`_edge_values`.
+EDGE_GATHER_IMPLS = ("segment", "cumsum", "cumsum_mxu")
+
+
 def _edge_values(dg: DeviceGraph, weighted_ranks: jax.Array) -> jax.Array:
     """Per-edge contribution ``weighted_ranks[src] (* w(src, dst))`` — the
     one place the optional edge-weight multiply lives for the raw-edge
-    impls (segment/cumsum/cumsum_mxu/pallas share it)."""
-    per_edge = weighted_ranks[dg.src]
+    impls (:data:`EDGE_GATHER_IMPLS` share it)."""
+    per_edge = gather_rows(weighted_ranks, dg.src)
     if dg.edge_weight is not None:
         per_edge = per_edge * dg.edge_weight
     return per_edge
@@ -584,17 +634,16 @@ def hybrid_rowsum(rows: jax.Array) -> jax.Array:
     """Dense-head row reduction: ``[R, W] -> [R]`` as one MXU matvec
     against a ones vector (the RankMap-style blocked contraction).  On a
     TPU the Pallas kernel streams the row matrix through VMEM in one HBM
-    pass; elsewhere the plain dot is what XLA lowers best (the interpreter
-    at bench scale would be pointless).  The branch is picked for the
-    platform the program is lowered for, so a compile for a described chip
-    gets the kernel too."""
+    pass; elsewhere a plain row sum (the interpreter at bench scale would
+    be pointless), which adds in one order whether or not XLA fuses the
+    gather that made the rows into it: the CPU's dot does not.  The branch
+    is picked for the platform the program is lowered for, so a compile for
+    a described chip gets the kernel too."""
     from page_rank_and_tfidf_using_apache_spark_tpu.ops import pallas_kernels as pk
 
-    def dot(r):
-        ones = jnp.ones((r.shape[1],), r.dtype)
-        return jnp.matmul(r, ones, precision=jax.lax.Precision.HIGHEST)
-
-    return jax.lax.platform_dependent(rows, tpu=pk.rowsum_pallas, default=dot)
+    return jax.lax.platform_dependent(
+        rows, tpu=pk.rowsum_pallas, default=lambda r: r.sum(axis=1)
+    )
 
 
 def spmv_hybrid(dg: DeviceGraph, weighted_ranks: jax.Array, n: int) -> jax.Array:

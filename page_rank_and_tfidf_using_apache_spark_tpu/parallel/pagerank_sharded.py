@@ -791,10 +791,10 @@ def make_sharded_runner(sg: ShardedGraph, cfg: PageRankConfig, mesh: Mesh):
             )  # [d*b_pad]: every shard's outgoing boundary values
             lookup = ob.boundary_lookup(wt, btable, wh)
             tail_contrib = ops.sorted_segment_sum(
-                lookup[tsrc[0]] * tw[0], tdst[0], block
+                ops.gather_rows(lookup, tsrc[0]) * tw[0], tdst[0], block
             )
             buf = ops.sorted_segment_sum(
-                lookup[hsrc[0]] * hw[0], hslot[0], h_pad + 2
+                ops.gather_rows(lookup, hsrc[0]) * hw[0], hslot[0], h_pad + 2
             )
             if redistribute:
                 # head part is replicated: each device contributes 1/d of
@@ -862,7 +862,7 @@ def make_sharded_runner(sg: ShardedGraph, cfg: PageRankConfig, mesh: Mesh):
         # state: replicated full rank vector; one psum per iteration.
         def step(ranks, src, dst, valid, ip, inv, dang, e):
             weighted = ranks * inv
-            per_edge = weighted[src[0]] * valid[0]
+            per_edge = ops.gather_rows(weighted, src[0]) * valid[0]
             partial = local_reduce(per_edge, dst[0], ip[0], n_pad)
             contribs = coll.psum(partial, axis)  # the reduceByKey, on ICI
             if redistribute:
@@ -885,14 +885,14 @@ def make_sharded_runner(sg: ShardedGraph, cfg: PageRankConfig, mesh: Mesh):
 
         def step(ranks, src, dst, valid, ip, hsrc, hnode, inv, dang, e):
             weighted = ranks * inv
-            per_edge = weighted[src[0]] * valid[0]
+            per_edge = ops.gather_rows(weighted, src[0]) * valid[0]
             partial = ops.sorted_segment_sum(per_edge, dst[0], n_pad,
                                              indptr=ip[0])
             if has_head:
                 w_ext = jnp.concatenate(
                     [weighted, jnp.zeros(1, weighted.dtype)]
                 )
-                row_sums = ops.hybrid_rowsum(w_ext[hsrc[0]])
+                row_sums = ops.hybrid_rowsum(ops.gather_rows(w_ext, hsrc[0]))
                 partial = partial.at[hnode[0]].add(row_sums)
             contribs = coll.psum(partial, axis)
             if redistribute:
@@ -916,7 +916,7 @@ def make_sharded_runner(sg: ShardedGraph, cfg: PageRankConfig, mesh: Mesh):
 
         def step(ranks_b, src, dst, valid, ip, inv_b, dang_b, e_b):
             weighted_b = ranks_b * inv_b  # [block], local
-            per_edge = weighted_b[src[0]] * valid[0]
+            per_edge = ops.gather_rows(weighted_b, src[0]) * valid[0]
             partial = local_reduce(per_edge, dst[0], ip[0], n_pad)
             contrib_b = exchange(partial, axis)  # [block]
             if redistribute:
@@ -934,7 +934,7 @@ def make_sharded_runner(sg: ShardedGraph, cfg: PageRankConfig, mesh: Mesh):
         # degree-weighted ranks, psum only the dangling-mass scalar.
         def step(ranks_b, src, dst_local, valid, ip, inv_b, dang_b, e_b):
             weighted_full = coll.all_gather(ranks_b * inv_b, axis)
-            per_edge = weighted_full[src[0]] * valid[0]
+            per_edge = ops.gather_rows(weighted_full, src[0]) * valid[0]
             contrib_b = local_reduce(per_edge, dst_local[0], ip[0], block)
             if redistribute:
                 dmass = coll.psum(jnp.sum(ranks_b * dang_b), axis)
@@ -1025,6 +1025,20 @@ def shard_segment_reduce(sg: ShardedGraph, spmv_impl: str) -> str | None:
     return ops.segment_reduce_for(sg.dst.shape[1], has_indptr=True)
 
 
+def shard_gather_chunks(sg: ShardedGraph) -> dict[str, int]:
+    """How many chunks each shard's per-edge rank-table gathers run in
+    (:func:`ops.gather_chunks`): the tail's and the head's, 1 where the
+    gather is whole or the strategy has no head."""
+    if sg.strategy == "owned":
+        tail, head = sg.owned.tail_src_idx, sg.owned.head_src_idx
+    else:
+        tail, head = sg.src, sg.head_src
+    return {
+        "tail": ops.gather_chunks(tail.shape[1:]),
+        "head": 1 if head is None else ops.gather_chunks(head.shape[1:]),
+    }
+
+
 class _ShardedExec:
     """Everything welded to ONE mesh: the partition, the device-resident
     graph arrays, the state sharding, and the callables run_segments
@@ -1070,6 +1084,7 @@ class _ShardedExec:
             put_secs=t_put.elapsed,
             comm_bytes_per_step=self.comm_bytes_per_step,
             segment_reduce=shard_segment_reduce(self.sg, cfg.spmv_impl),
+            gather_chunks=shard_gather_chunks(self.sg),
         )
         axis = mesh.axis_names[0]
         self._cfg = cfg

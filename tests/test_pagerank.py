@@ -240,6 +240,88 @@ def test_put_graph_record_names_segment_reduce(impl, edges, reduce):
     res = run_pagerank(synthetic_powerlaw(300, edges, seed=3), cfg)
     (rec,) = [r for r in res.metrics.records if r.get("event") == "put_graph"]
     assert rec["segment_reduce"] == reduce
+    # the layout impls gather from their own layout, not through _edge_values
+    assert rec["gather_chunks"] == (1 if impl == "segment" else None)
+
+
+@pytest.mark.parametrize(
+    "shape,cap,chunks",
+    [
+        ((1000,), None, 1),  # under the module's cap: the plain gather
+        ((1000,), 1000, 1),  # at the cap
+        ((1000,), 250, 4),  # chunks that divide the leading axis
+        ((1001,), 300, 4),  # ragged: the last chunk overlaps its predecessor
+        ((999,), 100, 10),
+        ((60, 8), 160, 3),  # 2-D: whole rows a chunk, dividing
+        ((61, 8), 160, 4),  # 2-D ragged
+        ((5, 8), 4, 5),  # a row alone above the cap: one row a chunk
+    ],
+)
+def test_gather_rows_equals_plain_gather(shape, cap, chunks, monkeypatch):
+    import jax
+
+    from page_rank_and_tfidf_using_apache_spark_tpu.ops import pagerank as ops
+
+    if cap is not None:
+        monkeypatch.setattr(ops, "GATHER_CAP", cap)
+    rng = np.random.default_rng(sum(shape))
+    table = rng.standard_normal(777).astype(np.float32)
+    idx = rng.integers(0, table.size, shape).astype(np.int32)
+    assert ops.gather_chunks(shape) == chunks
+    got = jax.jit(ops.gather_rows)(table, idx)
+    assert got.dtype == table.dtype
+    np.testing.assert_array_equal(np.asarray(got), table[idx])
+
+
+def test_runner_at_webgoogle_shape_keeps_its_gather_whole(monkeypatch):
+    """At web-Google's 5,105,039 edges the segment runner's gather is under
+    the cap: its lowering is the one it has with no cap at all, with one
+    while (the iteration loop).  A cap under the edge count adds the
+    chunk loop."""
+    import jax
+    import jax.numpy as jnp
+
+    from page_rank_and_tfidf_using_apache_spark_tpu.ops import pagerank as ops
+
+    n, e = 875_713, 5_105_039
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype)
+
+    dg = ops.DeviceGraph(
+        src=shape(e, dtype=jnp.int32), dst=shape(e, dtype=jnp.int32),
+        inv_outdeg=shape(n), dangling=shape(n), has_outlinks=shape(n),
+        indptr=shape(n + 1, dtype=jnp.int32),
+    )
+    cfg = PageRankConfig(iterations=20, dangling="redistribute", init="uniform",
+                         dtype="float32")
+
+    def lowered(cap):
+        monkeypatch.setattr(ops, "GATHER_CAP", cap)
+        return ops.make_pagerank_runner(n, cfg).lower(
+            dg, shape(n), shape(n)).as_text()
+
+    today = lowered(ops.GATHER_CAP)
+    assert ops.gather_chunks((e,)) == 1
+    assert today == lowered(1 << 62)
+    assert today.count("stablehlo.while") == 1
+    assert lowered(1 << 20).count("stablehlo.while") == 2
+
+
+def test_one_chip_chunked_gather_keeps_ranks(monkeypatch):
+    """The segment SpMV's gather in chunks gives the ranks of the whole
+    gather, bit for bit, and the put_graph record counts the chunks."""
+    from page_rank_and_tfidf_using_apache_spark_tpu.models.pagerank import run_pagerank
+    from page_rank_and_tfidf_using_apache_spark_tpu.ops import pagerank as ops
+
+    g = synthetic_powerlaw(300, 1500, seed=3)
+    cfg = PageRankConfig(iterations=20, dangling="redistribute", init="uniform")
+    whole = run_pagerank(g, cfg)
+    monkeypatch.setattr(ops, "GATHER_CAP", 200)
+    chunked = run_pagerank(g, cfg)
+    np.testing.assert_array_equal(chunked.ranks, whole.ranks)
+    (rec,) = [r for r in chunked.metrics.records if r.get("event") == "put_graph"]
+    assert rec["gather_chunks"] == ops.gather_chunks((g.n_edges,)) > 1
 
 
 @pytest.mark.parametrize("n", [0, 1, 5, 512, 513, 128 * 9, 40_001])

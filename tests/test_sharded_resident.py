@@ -14,6 +14,7 @@ import pytest
 from page_rank_and_tfidf_using_apache_spark_tpu import PageRankConfig
 from page_rank_and_tfidf_using_apache_spark_tpu.io import from_edges, synthetic_powerlaw
 from page_rank_and_tfidf_using_apache_spark_tpu.models.pagerank import run_pagerank
+from page_rank_and_tfidf_using_apache_spark_tpu.ops import pagerank as ops
 from page_rank_and_tfidf_using_apache_spark_tpu.parallel import (
     ShardedPageRank,
     make_mesh,
@@ -224,3 +225,20 @@ def test_scan_reduce_holds_float32(graph):
         one = run_pagerank(graph, cfg).ranks
         got = ShardedPageRank(graph, cfg, n_devices=4, strategy="edges").run().ranks
     assert np.abs(got - exact).sum() <= 3 * max(np.abs(one - exact).sum(), 1e-7)
+
+
+@pytest.mark.parametrize("strategy", ["hybrid", "owned"])
+def test_chunked_gathers_keep_ranks_bit_identical(graph, strategy, monkeypatch):
+    """With a cap under each shard's tail and head index counts, every
+    per-edge gather of the step runs in chunks (ragged ones included: 967
+    tail arcs a device under ``hybrid``, 1,930 head slots under ``owned``),
+    and the ranks are those of the whole gathers, bit for bit."""
+    whole = ShardedPageRank(graph, CFG, n_devices=4, strategy=strategy)
+    ranks = whole.run().ranks
+    monkeypatch.setattr(ops, "GATHER_CAP", 100)
+    chunked = ShardedPageRank(graph, CFG, n_devices=4, strategy=strategy)
+    np.testing.assert_array_equal(chunked.run().ranks, ranks)
+    (part,) = _records(whole.metrics, "partition")
+    assert part["gather_chunks"] == {"tail": 1, "head": 1}
+    (part,) = _records(chunked.metrics, "partition")
+    assert part["gather_chunks"]["tail"] > 1 and part["gather_chunks"]["head"] > 1

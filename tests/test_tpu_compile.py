@@ -16,6 +16,8 @@ compiled for a described chip cannot be read back without one).
 
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -187,3 +189,53 @@ def test_sharded_runner_compiles_for_v5e_mesh(strategy, topo, f32):
     text = compiled.as_text()
     assert "all-reduce" in text or "collective-permute" in text
     assert _device_bytes(compiled) < V5E_HBM_BYTES
+
+
+def test_sharded_hybrid_gathers_read_the_rank_table_from_vmem(topo, f32):
+    """The ``hybrid`` step at ``pagerank.graph500-25.4chip``'s shapes a
+    chip (130,940,928 tail arcs, 1,077,248 head rows of 128, 17,072,128
+    nodes): each per-edge gather, the tail's from the rank table and the
+    head rows' from the table with its zero slot, reads its table from
+    VMEM (``S(1)``), because each runs in chunks of at most
+    ``ops.GATHER_CAP`` indices.  Whole, both read it from HBM.  The graph
+    is zero-stride views: only the shapes are compiled."""
+    from page_rank_and_tfidf_using_apache_spark_tpu.parallel import (
+        pagerank_sharded as ps,
+    )
+    from page_rank_and_tfidf_using_apache_spark_tpu.parallel.mesh import NODES_AXIS
+
+    d, e_dev, rows, width, block = 4, 130_940_928, 1_077_248, 128, 4_268_032
+    n_pad = d * block
+
+    def zeros(shape, dtype):
+        return np.broadcast_to(np.zeros((), dtype), shape)
+
+    sg = ps.ShardedGraph(
+        strategy="hybrid", n=n_pad, n_pad=n_pad, block=block,
+        src=zeros((d, e_dev), np.int32), dst=zeros((d, e_dev), np.int32),
+        valid=zeros((d, e_dev), np.float32),
+        inv_outdeg=zeros((n_pad,), np.float32),
+        dangling=zeros((n_pad,), np.float32), pad_frac=0.0, node_map=None,
+        local_indptr=zeros((d, n_pad + 1), np.int32),
+        head_src=zeros((d, rows, width), np.int32),
+        head_node=zeros((d, rows), np.int32),
+    )
+    assert ps.shard_gather_chunks(sg) == {"tail": 16, "head": 17}
+    mesh = Mesh(np.array(topo.devices[:d]), (NODES_AXIS,))
+    cfg = PageRankConfig(iterations=20, dangling="redistribute", init="uniform",
+                         dtype="float32")
+    graph_args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh)
+                  for a, sh in ps.sharded_graph_layout(sg, mesh)]
+    vec = jax.ShapeDtypeStruct((n_pad,), jnp.float32,
+                               sharding=NamedSharding(mesh, P()))
+    text = ps.make_sharded_runner(sg, cfg, mesh).lower(
+        vec, *graph_args, vec).compile().as_text()
+    shapes = dict(re.findall(r"^\s*(?:ROOT )?%(\S+) = (\S+) ", text, re.M))
+    tables = [shapes[name] for name in re.findall(r" gather\(%([^,\s)]+)", text)]
+    # the per-edge gathers read the [n_pad] or [n_pad + 1] rank table; the
+    # read at each node's last edge gathers from the edge-long scan
+    rank_tables = [t for t in tables
+                   if t.startswith((f"f32[{n_pad}]", f"f32[{n_pad + 1}]"))]
+    assert {t.split("]")[0] for t in rank_tables} == {
+        f"f32[{n_pad}", f"f32[{n_pad + 1}"}
+    assert all("S(1)" in t for t in rank_tables), rank_tables
