@@ -1,6 +1,6 @@
 """Multi-chip PageRank + TF-IDF via the library API (SURVEY.md §2.2 R1–R3).
 
-Demonstrates every shard strategy over a device mesh — on real chips when a
+Demonstrates both sharded layouts over a device mesh — on real chips when a
 TPU pod is attached, or on simulated devices anywhere:
 
     JAX_PLATFORMS=cpu \
@@ -37,11 +37,10 @@ single = run_pagerank(graph, cfg).ranks
 
 print(f"mesh: {d} devices; auto strategy -> "
       f"{auto_select_strategy(graph, d)!r}")
-for strategy in ("edges", "nodes", "nodes_balanced", "src", "src_ring",
-                 "hybrid"):
+for strategy in ("hybrid", "owned"):
     res = run_pagerank_sharded(graph, cfg, mesh=mesh, strategy=strategy)
     l1 = np.abs(res.ranks - single).sum()
-    print(f"pagerank[{strategy:14s}] on {d} devices: L1 vs single-chip {l1:.2e}")
+    print(f"pagerank[{strategy:6s}] on {d} devices: L1 vs single-chip {l1:.2e}")
 
 docs = [f"alpha w{i % 17} w{i % 5} beta{i % 3}" for i in range(512)]
 chunks = [docs[i:i + 64] for i in range(0, len(docs), 64)]

@@ -672,7 +672,7 @@ def _shrink_chain(d0: int) -> list[int]:
     return chain
 
 
-def _sharded_pagerank_traceable(strategy: str) -> Traceable:
+def _build_pagerank_sharded_hybrid() -> Traceable:
     import jax
 
     from page_rank_and_tfidf_using_apache_spark_tpu.io.graph import synthetic_powerlaw
@@ -691,24 +691,21 @@ def _sharded_pagerank_traceable(strategy: str) -> Traceable:
     variants: list[tuple[str, tuple]] = []
     for d in _shrink_chain(min(4, len(jax.devices()))):
         mesh = make_mesh(d, NODES_AXIS)
-        sg = ps.partition_graph(graph, d, strategy=strategy)
+        sg = ps.partition_graph(graph, d, strategy="hybrid")
         runners[d] = ps.make_sharded_runner(sg, cfg, mesh)
-        head = (
-            (_i32(sg.head_src.shape), _i32(sg.head_node.shape))
-            if strategy == "hybrid" else ()
-        )
         args = (
             _f32((sg.n_pad,)),
             _i32(sg.src.shape),
             _i32(sg.dst.shape),
             _f32(sg.valid.shape),
             _i32(sg.local_indptr.shape),
-            *head,
+            _i32(sg.head_src.shape),
+            _i32(sg.head_node.shape),
             _f32((sg.n_pad,)),
             _f32((sg.n_pad,)),
             _f32((sg.n_pad,)),
         )
-        variants.append((f"{strategy}-d{d}", args))
+        variants.append((f"hybrid-d{d}", args))
 
     def dispatch(ranks, src, *rest):
         # per-device-count runners: the edge arrays are [d, e_dev], so the
@@ -722,8 +719,8 @@ def _sharded_pagerank_traceable(strategy: str) -> Traceable:
     )
 
 
-def _sharded_pad_plan(strategy: str):
-    """Static padding-waste plan points for a sharded entry: pad_frac of
+def _hybrid_pad_plan():
+    """Static padding-waste plan points for the sharded entry: pad_frac of
     the partition *plan* (parallel.pagerank_sharded.plan_partition — no
     arrays materialized, no dispatch) on the registry's trace graph, one
     point per device count on the elastic shrink chain."""
@@ -740,10 +737,7 @@ def _sharded_pad_plan(strategy: str):
 
         graph = synthetic_powerlaw(64, 256, seed=1)
         return [
-            (
-                f"{strategy}-d{d}",
-                plan_partition(graph, d, strategy=strategy).pad_frac,
-            )
+            (f"hybrid-d{d}", plan_partition(graph, d, strategy="hybrid").pad_frac)
             for d in _shrink_chain(min(4, len(jax.devices())))
         ]
 
@@ -1010,22 +1004,6 @@ def _build_components_sharded_owned() -> Traceable:
     dispatch, variants = _owned_pair_variants("cc")
     return Traceable(fn=dispatch, variants=variants,
                      anchor=ws.make_components_sharded_runner)
-
-
-def _build_pagerank_sharded_edges() -> Traceable:
-    return _sharded_pagerank_traceable("edges")
-
-
-def _build_pagerank_sharded_hybrid() -> Traceable:
-    return _sharded_pagerank_traceable("hybrid")
-
-
-def _build_pagerank_sharded_nodes_balanced() -> Traceable:
-    return _sharded_pagerank_traceable("nodes_balanced")
-
-
-def _build_pagerank_sharded_src() -> Traceable:
-    return _sharded_pagerank_traceable("src")
 
 
 # -------------------------------------------------------------------- tfidf
@@ -1535,52 +1513,6 @@ ENTRY_POINTS: tuple[EntryPoint, ...] = (
         intensity_floor=0.045,
     ),
     EntryPoint(
-        name="pagerank_sharded_edges",
-        module=f"{_PKG}/parallel/pagerank_sharded.py",
-        build=_build_pagerank_sharded_edges,
-        watch=(
-            f"{_PKG}/ops/pagerank.py",
-            f"{_PKG}/dataflow/fixpoint.py",
-            f"{_PKG}/parallel/mesh.py",
-            f"{_PKG}/parallel/collectives.py",
-        ),
-        axes=("nodes",),
-        # one psum per iteration: the contribs combine (replicated state
-        # needs no dangling-mass or delta collective)
-        collective_budget=1,
-        # one compile per device count on the elastic shrink chain (4,2,1)
-        max_compiles=3,
-        # equal contiguous edge slices: padding is only the ceil remainder
-        pad_plan=_sharded_pad_plan("edges"),
-        pad_frac_ceiling=0.05,
-        intensity_floor=0.035,  # static model: 0.047 at d=1 (worst)
-    ),
-    EntryPoint(
-        name="pagerank_sharded_nodes_balanced",
-        module=f"{_PKG}/parallel/pagerank_sharded.py",
-        build=_build_pagerank_sharded_nodes_balanced,
-        watch=(
-            f"{_PKG}/ops/pagerank.py",
-            f"{_PKG}/dataflow/fixpoint.py",
-            f"{_PKG}/parallel/mesh.py",
-            f"{_PKG}/parallel/collectives.py",
-        ),
-        axes=("nodes",),
-        # all_gather(weighted ranks) + psum(dangling mass) + psum(delta)
-        collective_budget=3,
-        # one compile per device count on the elastic shrink chain (4,2,1)
-        max_compiles=3,
-        # RATCHETED with the hybrid/power-law PR: the optimal min-max
-        # boundary search (plan_partition) brought the trace-graph worst
-        # point from 0.47 to 0.10 at d=4 (and the 8-device dryrun plan
-        # from 0.61 to 0.47, its node-granularity floor — one hub's
-        # in-edge run cannot split across devices in this layout; the
-        # 'hybrid' strategy exists to go below that floor).
-        pad_plan=_sharded_pad_plan("nodes_balanced"),
-        pad_frac_ceiling=0.25,
-        intensity_floor=0.035,  # static model: 0.045 at d=4 (worst)
-    ),
-    EntryPoint(
         name="pagerank_sharded_hybrid",
         module=f"{_PKG}/parallel/pagerank_sharded.py",
         build=_build_pagerank_sharded_hybrid,
@@ -1599,8 +1531,8 @@ ENTRY_POINTS: tuple[EntryPoint, ...] = (
         # row/edge-granular splits: only dense-row sentinels and two ceil
         # remainders pad (0.21 at d=4 on the hub-dense 256-edge trace
         # graph; 0.0001 at web-Google scale, where the ROADMAP "pad_frac
-        # below 0.25 for the balanced strategies" goal is measured)
-        pad_plan=_sharded_pad_plan("hybrid"),
+        # below 0.25" goal is measured)
+        pad_plan=_hybrid_pad_plan(),
         pad_frac_ceiling=0.25,
         intensity_floor=0.04,  # static model: 0.052 at d=4 (worst)
     ),
@@ -1635,26 +1567,6 @@ ENTRY_POINTS: tuple[EntryPoint, ...] = (
         # the strategy's reason to exist, so the carry may not double
         donate=(0,),
         intensity_floor=0.03,  # static model: 0.042 at d=4 (worst)
-    ),
-    EntryPoint(
-        name="pagerank_sharded_src",
-        module=f"{_PKG}/parallel/pagerank_sharded.py",
-        build=_build_pagerank_sharded_src,
-        watch=(
-            f"{_PKG}/ops/pagerank.py",
-            f"{_PKG}/dataflow/fixpoint.py",
-            f"{_PKG}/parallel/mesh.py",
-            f"{_PKG}/parallel/collectives.py",
-        ),
-        axes=("nodes",),
-        # reduce-scatter exchange + psum(dangling mass) + psum(delta)
-        collective_budget=3,
-        # one compile per device count on the elastic shrink chain (4,2,1)
-        max_compiles=3,
-        # push layout: out-degree is the bounded axis, padding stays small
-        pad_plan=_sharded_pad_plan("src"),
-        pad_frac_ceiling=0.25,
-        intensity_floor=0.03,  # static model: 0.040 at d=4 (worst)
     ),
     EntryPoint(
         name="hits_sharded_owned",

@@ -79,15 +79,14 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--mesh", type=int, default=0,
                    help="shard over this many devices (0 = single device)")
     p.add_argument("--shard-strategy",
-                   choices=["auto", "edges", "nodes", "nodes_balanced",
-                            "src", "src_ring", "hybrid", "owned"],
+                   choices=["auto", "hybrid", "owned"],
                    default="auto",
-                   help="graph partition under --mesh: auto (by memory "
-                        "footprint + degree shape) / balanced edge slices / "
-                        "node blocks / edge-balanced node blocks (power-law) "
-                        "/ source-block push with reduce-scatter (or "
-                        "explicit ppermute-ring) exchange / degree-aware "
-                        "hybrid (dense MXU head rows + tail edge slices)")
+                   help="graph layout under --mesh: auto (hybrid while the "
+                        "replicated state fits half of a chip's memory, "
+                        "else owned) / hybrid (replicated ranks: equal "
+                        "edge slices, dense head rows when the power-law "
+                        "head covers a quarter of the arcs) / owned (rank "
+                        "slices with a sparse boundary exchange)")
     return p
 
 

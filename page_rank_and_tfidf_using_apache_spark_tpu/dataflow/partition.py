@@ -2,11 +2,11 @@
 
 The RDD analog (SURVEY.md L3 ``partitionBy``): ONE logical global array
 plus the layout bookkeeping that maps it onto devices — padded length,
-the global-id → padded-slot relabeling a partition strategy chose
-(``parallel.pagerank_sharded.plan_partition``), and the mesh sharding the
-device value carries.  Callers program against the logical view; the
+a global-id → padded-slot relabeling, and the mesh sharding the device
+value carries.  Callers program against the logical view; the
 padding/relabeling round-trip lives here once instead of inside each
-runner (``_ShardedExec.put_ranks`` / ``extract_np`` are thin calls now).
+runner (the owned sharded layout's :class:`OwnedArray` backs
+``_ShardedExec.put_ranks``).
 
 The host→device direction pads and places; the device→host direction is
 a *guarded* pull (resilience executor: retry / sync deadline /
@@ -47,9 +47,9 @@ class PartitionedArray:
     @classmethod
     def from_plan(cls, n: int, n_pad: int, node_map: np.ndarray,
                   sharding: Any = None) -> "PartitionedArray":
-        """Layout from a partition plan's bookkeeping (the sharded
-        runners pass ``ShardedGraph.n/n_pad/node_map`` + their state
-        sharding)."""
+        """Layout from a partition plan's bookkeeping: logical and padded
+        lengths, the global-id -> padded-slot map and the state
+        sharding."""
         return cls(n=n, n_pad=n_pad, node_map=node_map, sharding=sharding)
 
     def put(self, global_np: np.ndarray, dtype=None) -> "PartitionedArray":

@@ -1,11 +1,10 @@
 """Owned-partition boundary machinery (ISSUE 15): the host planner and
 device-side gather/scatter helpers under the ``owned`` sharded strategy.
 
-The replicated-state wall: every pre-ISSUE-15 sharded PageRank either
-replicates the full rank vector (``edges``/``hybrid`` — O(n) state per
-chip, one O(n)-byte dense ``psum``) or gathers it per step (``nodes*`` —
-O(n)-byte ``all_gather``).  Both stop fitting/paying at 10-100x web-Google
-node counts (ROADMAP).  *Sparse Allreduce* (PAPERS.md) observes that on a
+The replicated-state wall: the replicated sharded PageRank layout
+(``hybrid``) holds the full rank vector on every chip — O(n) state per
+chip, one O(n)-byte dense ``psum`` a step — which stops fitting/paying at
+10-100x web-Google node counts (ROADMAP).  *Sparse Allreduce* (PAPERS.md) observes that on a
 power-law graph the partition cut is dominated by a small hub set: peel
 the hubs into a tiny replicated mini-state and the remaining cut-crossing
 ("boundary") entries are a sublinear fraction of n — so exchanging ONLY
@@ -22,8 +21,8 @@ Layout (one :class:`OwnedPlan`, materialized as one :class:`OwnedShard`):
   touched by almost every shard, so their rank state is REPLICATED
   ([H_pad] mini-vector) and their in-edge contributions are combined by
   ONE small dense ``psum`` — cheaper than exchanging them.  Head in-edges
-  are dealt across devices at edge granularity, which also removes the
-  node-granularity load floor ``nodes_balanced`` hits on hubs.
+  are dealt across devices at edge granularity, so no hub's in-degree
+  sets a node-granularity load floor.
 - **tail** — every other node, partitioned into d contiguous owned
   blocks at equal tail-in-edge splits (min-max optimal, node count per
   device capped at 2x the even block).  Each shard holds ONLY its
@@ -140,9 +139,9 @@ class OwnedPlan(NamedTuple):
 
 def _minmax_tail_split(tail_ip: np.ndarray, nt: int, d: int) -> np.ndarray:
     """Optimal min-max contiguous split of the tail nodes at equal
-    tail-in-edge widths (binary search + greedy max-fill — the
-    ``nodes_balanced`` planner's algorithm over tail-rank space), node
-    count per device capped at 2x the even block."""
+    tail-in-edge widths (binary search over the padded width + greedy
+    max-fill feasibility, over tail-rank space), node count per device
+    capped at 2x the even block."""
     bounds = np.zeros(d + 1, np.int64)
     if nt == 0:
         return bounds

@@ -2,16 +2,16 @@
 
 Reference counterpart (SURVEY.md §5.8): the sort-based shuffle + netty
 transport + torrent broadcast stack under every ``reduceByKey``/``join``.
-The rebuild's entire communication vocabulary is four collectives, all
-compiled into the iteration program by XLA and scheduled on ICI/DCN:
+The rebuild's communication vocabulary is three collectives, all compiled
+into the iteration program by XLA and scheduled on ICI/DCN:
 
 - ``psum``          cross-chip combine (the shuffle-reduce; BASELINE.json:5
-                    "allreduced over ICI via lax.psum")
-- ``all_gather``    reassemble a sharded vector (the map-side fetch)
-- ``reduce_scatter`` combine + re-shard in one step (psum that keeps only
-                    your block — halves the bytes when output stays sharded)
-- ``ppermute_ring`` neighbor exchange (the edge-cut / block-rotation
-                    primitive for 2-D shardings, SURVEY.md §2.3)
+                    "allreduced over ICI via lax.psum"): the replicated
+                    PageRank layout's one collective a step, the owned
+                    layout's head combine, sharded TF-IDF's DF
+- ``pmax``          cross-chip max (sharded HITS' normalization)
+- ``butterfly_all_gather`` the owned layouts' boundary exchange, as
+                    log2(D) ``ppermute`` rounds
 
 Kept as a module so the communication surface is explicit, greppable, and
 mockable in tests — not because the wrappers add logic.
@@ -26,56 +26,6 @@ from jax import lax
 
 def psum(x: jax.Array, axis: str) -> jax.Array:
     return lax.psum(x, axis)
-
-
-def all_gather(x: jax.Array, axis: str) -> jax.Array:
-    """Tiled gather: [B] per device → [D*B] on every device."""
-    return lax.all_gather(x, axis, tiled=True)
-
-
-def reduce_scatter(x: jax.Array, axis: str) -> jax.Array:
-    """[D*B] per device → summed, then each device keeps its [B] block."""
-    return lax.psum_scatter(x, axis, tiled=True)
-
-
-def ppermute_ring(x: jax.Array, axis: str, *, shift: int = 1) -> jax.Array:
-    """Rotate block ``x`` ``shift`` steps around the mesh ring."""
-    n = axis_size(axis)
-    perm = [(i, (i + shift) % n) for i in range(n)]
-    return lax.ppermute(x, axis, perm)
-
-
-def ring_reduce_scatter(partial: jax.Array, axis: str) -> jax.Array:
-    """Explicit ring reduce-scatter over ``ppermute``: the edge-cut exchange
-    of SURVEY.md §2.3/§5.8 written out hop by hop.
-
-    ``partial``: [D*B] per-destination partial sums on every device.  D-1
-    steps; at each step the accumulating [B] block rotates one hop forward
-    (i → i+1) on the ICI ring while the receiver folds in its local partial
-    for that block.  Device i ends holding the complete sum for block i —
-    bit-identical (up to float add order) to :func:`reduce_scatter`, which
-    tests pin.  Exists as the hand-scheduled alternative so the exchange's
-    per-hop structure (compute/comm overlap inside the scanned loop body)
-    is explicit rather than delegated to XLA's psum_scatter lowering.
-    """
-    d = axis_size(axis)
-    if d == 1:
-        return partial
-    i = lax.axis_index(axis)
-    chunks = partial.reshape(d, -1)
-
-    def chunk(c):
-        return lax.dynamic_index_in_dim(chunks, c % d, 0, keepdims=False)
-
-    # Device i seeds with its partial for block (i-1); each hop the carried
-    # block index drops by one, so after D-1 hops it holds block i complete.
-    acc = chunk(i - 1)
-
-    def body(s, acc):
-        acc = ppermute_ring(acc, axis, shift=1)  # receive from device i-1
-        return acc + chunk(i - 2 - s)
-
-    return lax.fori_loop(0, d - 1, body, acc)
 
 
 def pmax(x: jax.Array, axis: str) -> jax.Array:
@@ -118,10 +68,6 @@ def butterfly_all_gather(block: jax.Array, axis: str) -> jax.Array:
         recv = lax.ppermute(chunk, axis, perm)
         buf = lax.dynamic_update_slice_in_dim(buf, recv, partner_base, axis=0)
     return buf.reshape((d * block.shape[0],) + block.shape[1:])
-
-
-def axis_index(axis: str) -> jax.Array:
-    return lax.axis_index(axis)
 
 
 def axis_size(axis: str) -> int:

@@ -20,9 +20,9 @@ that into a degradation rung for the sharded runners:
   nothing survives (None -> the caller's ladder is exhausted).
 
 The runner-side halves live next to the runners: ``parallel/
-pagerank_sharded.py`` re-partitions the graph over the new mesh (the
-``nodes_balanced`` planner re-balances edge splits for the surviving
-device count) and ``parallel/tfidf_sharded.py`` re-slices the in-flight
+pagerank_sharded.py`` re-partitions the graph over the new mesh (its
+layout planned again for the surviving device count) and
+``parallel/tfidf_sharded.py`` re-slices the in-flight
 super-chunk; ``models/driver.py`` orchestrates the rung inside the
 segment loop.  Every shrink publishes a ``mesh.shrink`` span and ONE
 ``degraded`` event carrying old/new device counts, so a degraded run is

@@ -21,7 +21,7 @@ import numpy as np
 import pytest
 
 from page_rank_and_tfidf_using_apache_spark_tpu import obs
-from page_rank_and_tfidf_using_apache_spark_tpu.io import synthetic_powerlaw
+from page_rank_and_tfidf_using_apache_spark_tpu.io import from_edges, synthetic_powerlaw
 from page_rank_and_tfidf_using_apache_spark_tpu.models.pagerank import run_pagerank
 from page_rank_and_tfidf_using_apache_spark_tpu.parallel import (
     run_pagerank_sharded,
@@ -202,7 +202,8 @@ def test_run_guarded_all_rungs_fail_exhausts():
 # ----------------------------------------- end-to-end: sharded PageRank
 
 
-def test_pagerank_sharded_survives_device_loss_2to1(tmp_path):
+@pytest.mark.parametrize("strategy", ["hybrid", "owned"])
+def test_pagerank_sharded_survives_device_loss_2to1(tmp_path, strategy):
     """Acceptance: 2-device mesh, chaos kills logical device 1 -> the run
     completes via mesh shrink (no ResilienceExhausted), matches the
     uninterrupted ranks to atol 1e-6, recomputes zero committed
@@ -216,7 +217,8 @@ def test_pagerank_sharded_survives_device_loss_2to1(tmp_path):
     obs.start_run("elastic_pr", str(tmp_path / "tr"))
     try:
         with chaos.inject("*:device_lost@dev:1"):
-            res = run_pagerank_sharded(g, cfg, n_devices=2, metrics=m)
+            res = run_pagerank_sharded(g, cfg, n_devices=2, metrics=m,
+                                       strategy=strategy)
     finally:
         obs.end_run()
     np.testing.assert_allclose(res.ranks, base.ranks, atol=1e-6)
@@ -242,16 +244,16 @@ def test_pagerank_sharded_survives_device_loss_2to1(tmp_path):
 
 
 def test_pagerank_sharded_shrinks_4to2_nodes_balanced(tmp_path):
-    """A 4-device nodes_balanced mesh losing one device lands on the
-    mesh_shrink rung at 2 devices — the partition planner re-balances its
-    edge splits for the surviving count."""
+    """A 4-device mesh losing one device lands on the mesh_shrink rung at
+    2 devices — the partition is planned again for the surviving count
+    (name kept from the node-block layout this test once covered)."""
     g = synthetic_powerlaw(800, 3200, seed=13)
     base = run_pagerank(g, PageRankConfig(iterations=8, **GRAPH_KW))
     m = MetricsRecorder()
     with chaos.inject("*:device_lost@dev:3"):
         res = run_pagerank_sharded(
             g, PageRankConfig(iterations=8, **GRAPH_KW),
-            n_devices=4, strategy="nodes_balanced", metrics=m,
+            n_devices=4, strategy="hybrid", metrics=m,
         )
     np.testing.assert_allclose(res.ranks, base.ranks, atol=1e-6)
     degraded = [r for r in m.records if r.get("event") == "degraded"]
@@ -261,7 +263,7 @@ def test_pagerank_sharded_shrinks_4to2_nodes_balanced(tmp_path):
     assert [p["devices"] for p in parts] == [4, 2]  # repartitioned once
 
 
-@pytest.mark.parametrize("strategy", ["edges", "hybrid"])
+@pytest.mark.parametrize("strategy", ["hybrid-headless", "hybrid"])
 def test_pagerank_sharded_device_loss_at_result_pull(tmp_path, strategy):
     """Carried-forward hardening (a), ISSUE 7: a device loss FIRST
     surfacing at the sharded result-pull site (every segment already
@@ -270,13 +272,16 @@ def test_pagerank_sharded_device_loss_at_result_pull(tmp_path, strategy):
     rebuild the mesh over the survivor, re-run only the uncommitted
     iterations there, and pull from the rebuilt mesh."""
     g = synthetic_powerlaw(700, 2800, seed=11)
+    if strategy == "hybrid-headless":  # weighted arcs plan no dense head
+        w = np.random.default_rng(5).uniform(0.5, 2.0, g.n_edges)
+        g = from_edges(g.src, g.dst, weight=w, compact_ids=False)
     cfg = PageRankConfig(iterations=8, checkpoint_every=4,
                          checkpoint_dir=str(tmp_path / "ck"), **GRAPH_KW)
     base = run_pagerank(g, PageRankConfig(iterations=8, **GRAPH_KW))
     m = MetricsRecorder()
     with chaos.inject("pagerank_result_pull:device_lost@dev:1"):
         res = run_pagerank_sharded(g, cfg, n_devices=2, metrics=m,
-                                   strategy=strategy)
+                                   strategy="hybrid")
     np.testing.assert_allclose(res.ranks, base.ranks, atol=1e-6)
     assert res.iterations == 8
     degraded = [r for r in m.records if r.get("event") == "degraded"]

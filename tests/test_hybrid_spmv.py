@@ -9,11 +9,10 @@ The acceptance bars:
 - the static layouts account for every edge exactly once (the layout IS
   the graph, re-blocked);
 - ``plan_partition(strategy="hybrid")`` reports ``pad_frac <= 0.25`` on
-  the web-Google-scale graph at 8 devices, where the r05-measured
-  ``nodes_balanced`` padding was 0.61 — and the optimal min-max
-  ``nodes_balanced`` planner itself now beats that measured value;
-- chip-count invariance of the sharded ``hybrid`` strategy lives in
-  tests/test_parallel.py next to the other strategies.
+  the web-Google-scale graph at 8 devices, under the padding floor of any
+  layout that keeps each node's in-arcs on one device;
+- chip-count invariance of the sharded ``hybrid`` layout lives in
+  tests/test_parallel.py next to the owned layout.
 """
 
 from __future__ import annotations
@@ -31,7 +30,6 @@ from page_rank_and_tfidf_using_apache_spark_tpu.models.pagerank import run_pager
 from page_rank_and_tfidf_using_apache_spark_tpu.ops import pagerank as ops
 from page_rank_and_tfidf_using_apache_spark_tpu.ops import pallas_kernels as pk
 from page_rank_and_tfidf_using_apache_spark_tpu.parallel.pagerank_sharded import (
-    auto_select_strategy,
     plan_partition,
 )
 from page_rank_and_tfidf_using_apache_spark_tpu.utils.config import PageRankConfig
@@ -201,34 +199,16 @@ def test_hybrid_plan_beats_pad_ceiling_at_webgoogle_scale():
     """The ISSUE 7 acceptance pin, statically checkable on CPU: at the
     bench's web-Google scale (875K nodes / 5.1M edges, 8 devices) the
     hybrid plan's padding waste is ~1e-4 — far under the 0.25 ceiling the
-    registry now enforces — while the r05 dryrun measured 0.61 for
-    nodes_balanced (whose optimal planner now plans 0.43: its remaining
-    padding is the node-granularity floor a 780K-in-degree hub forces on
-    any layout that cannot split one node's run across devices)."""
+    registry enforces, and under the node-granularity floor that a
+    780K-in-degree hub forces on any layout that cannot split one node's
+    run across devices (the r05 dryrun measured 0.61 for such a layout)."""
     g = synthetic_powerlaw(875_000, 5_100_000, seed=7)
     plan = plan_partition(g, 8, strategy="hybrid")
     assert plan.pad_frac <= 0.25, plan
     head_k, w, rows, rows_dev = plan.head
     assert head_k >= 1 and rows_dev * 8 * w >= plan.head[2] * w
-    # the improved nodes_balanced planner beats the r05-measured 0.6123
-    nb = plan_partition(g, 8, strategy="nodes_balanced")
-    assert nb.pad_frac < 0.5
-    # ... but cannot beat its own node-granularity lower bound, which the
-    # hub's in-degree sets; hybrid goes below it by splitting dense rows
+    # the floor of a node-granular layout: the hub's in-degree on one
+    # device sets every device's padded width; hybrid splits dense rows
     indeg_max = int(np.diff(g.csr_indptr()).max())
     floor = (8 * indeg_max - g.n_edges) / (8 * indeg_max)
-    assert nb.pad_frac == pytest.approx(floor, abs=0.01)
     assert plan.pad_frac < floor
-
-
-def test_auto_select_prefers_hybrid_for_powerlaw_heads():
-    g = synthetic_powerlaw(500, 3000, seed=42)
-    # hub-heavy graph, generous budget -> the degree-aware hybrid layout
-    assert auto_select_strategy(g, 8) == "hybrid"
-    # no dense-worthy head (uniform ring) -> replicated 'edges'
-    n = 400
-    ring = from_edges(np.arange(n), (np.arange(n) + 1) % n)
-    assert auto_select_strategy(ring, 8) == "edges"
-    # starved budget picks the owned-slices layout (ISSUE 15 trigger:
-    # replicated state doesn't fit)
-    assert auto_select_strategy(g, 8, hbm_bytes=10_000) == "owned"

@@ -1,6 +1,6 @@
 """Distributed correctness on 8 simulated devices (SURVEY.md §4): the key
 test is chip-count invariance — same ranks/weights on 1, 2, 4, 8 devices —
-over the real psum/all_gather/shard_map code paths."""
+over the real psum/ppermute/shard_map code paths."""
 
 import numpy as np
 import pytest
@@ -15,6 +15,7 @@ from page_rank_and_tfidf_using_apache_spark_tpu.parallel import (
     run_pagerank_sharded,
     run_tfidf_sharded,
 )
+from page_rank_and_tfidf_using_apache_spark_tpu.parallel import pagerank_sharded as ps
 
 CFG = PageRankConfig(
     iterations=30, dangling="redistribute", init="uniform", dtype="float64"
@@ -26,184 +27,159 @@ def graph():
     return synthetic_powerlaw(500, 3000, seed=42)
 
 
-@pytest.fixture(scope="module")
-def single_chip_ranks(graph):
-    return run_pagerank(graph, CFG).ranks
+def _uniform_graph(n=500, k=6, seed=7):
+    """Every node takes at most ``k`` in-arcs, under the smallest dense row
+    width (8): the replicated layout plans no head."""
+    rng = np.random.default_rng(seed)
+    return from_edges(rng.integers(0, n, n * k), np.repeat(np.arange(n), k))
 
 
-@pytest.mark.parametrize("n_devices", [1, 2, 4, 8])
-@pytest.mark.parametrize(
-    "strategy",
-    ["edges", "nodes", "nodes_balanced", "src", "src_ring", "hybrid",
-     "owned"])
-def test_chip_count_invariance(graph, single_chip_ranks, n_devices, strategy):
-    res = run_pagerank_sharded(graph, CFG, n_devices=n_devices, strategy=strategy)
-    assert np.abs(res.ranks - single_chip_ranks).sum() <= 1e-9
-
-
-@pytest.mark.parametrize("impl", ["cumsum", "cumsum_mxu"])
-@pytest.mark.parametrize(
-    "strategy", ["edges", "nodes", "nodes_balanced", "src", "src_ring"])
-def test_sharded_cumsum_impl_matches_single_chip(
-        graph, single_chip_ranks, strategy, impl):
-    """The scatter-free monotone-diff SpMVs must agree with segment_sum in
-    every sharded layout (local_indptr correctness incl. padding slots —
-    and the indptr must actually be BUILT for every prefix-sum impl)."""
-    cfg = PageRankConfig(iterations=30, dangling="redistribute", init="uniform",
-                         dtype="float64", spmv_impl=impl)
-    res = run_pagerank_sharded(graph, cfg, n_devices=8, strategy=strategy)
-    assert np.abs(res.ranks - single_chip_ranks).sum() <= 1e-9
-
-
-def test_sharded_drop_and_one_init(graph):
-    """Spark-convention flags work sharded too (init ONE, dangling drop)."""
-    cfg = PageRankConfig(iterations=10, dtype="float64")
-    base = run_pagerank(graph, cfg).ranks
-    res = run_pagerank_sharded(graph, cfg, n_devices=4)
-    assert np.abs(res.ranks - base).sum() <= 1e-9
-
-
-def test_sharded_personalized(graph):
-    cfg = PageRankConfig(
+# (layout, graph): the replicated layout with its dense head, without one,
+# and the owned slices
+LAYOUTS = {"hybrid": ("hybrid", "powerlaw"),
+           "hybrid-headless": ("hybrid", "uniform"),
+           "owned": ("owned", "powerlaw")}
+MODES = {
+    "redistribute": CFG,
+    "drop-one": PageRankConfig(iterations=10, dtype="float64"),
+    "personalized": PageRankConfig(
         iterations=40, dangling="redistribute", init="uniform",
         personalize=(3, 17), dtype="float64",
-    )
-    base = run_pagerank(graph, cfg).ranks
-    res = run_pagerank_sharded(graph, cfg, n_devices=8, strategy="nodes")
+    ),
+}
+
+
+@pytest.fixture(scope="module")
+def graphs(graph):
+    return {"powerlaw": graph, "uniform": _uniform_graph()}
+
+
+def _layout(graphs, name):
+    strategy, which = LAYOUTS[name]
+    g = graphs[which]
+    # the fixture gives the layout it is named for
+    assert (ps.plan_partition(g, 4).head[0] > 0) is (which == "powerlaw"), name
+    return strategy, g
+
+
+@pytest.mark.parametrize("mode", list(MODES))
+@pytest.mark.parametrize("n_devices", [1, 2, 4, 8])
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_chip_count_invariance(graphs, layout, n_devices, mode):
+    """Same ranks as one chip on 1, 2, 4 and 8 devices, under the
+    semantics flags: dangling mass redistributed from a uniform start,
+    the Spark convention (dangling mass dropped, ranks start at one) and
+    a personalized restart."""
+    strategy, g = _layout(graphs, layout)
+    cfg = MODES[mode]
+    base = run_pagerank(g, cfg).ranks
+    res = run_pagerank_sharded(g, cfg, n_devices=n_devices, strategy=strategy)
     assert np.abs(res.ranks - base).sum() <= 1e-9
 
 
-def test_sharded_tolerance(graph):
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_sharded_tolerance(graphs, layout):
+    """A tolerance run stops with its delta under ``tol``.  The owned
+    layout's convergence gauge rides the head psum one step late, so it
+    stops at most one iteration after the one-chip run would."""
+    strategy, g = _layout(graphs, layout)
     cfg = PageRankConfig(
         iterations=500, tol=1e-10, dangling="redistribute", init="uniform",
         dtype="float64",
     )
-    res = run_pagerank_sharded(graph, cfg, n_devices=4)
+    one = run_pagerank(g, cfg)
+    res = run_pagerank_sharded(g, cfg, n_devices=4, strategy=strategy)
     assert res.iterations < 500
     assert res.l1_delta <= 1e-10
+    assert res.iterations <= one.iterations + 2
 
 
-def test_sharded_checkpoint_resume(graph, tmp_path):
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_sharded_checkpoint_resume(graphs, layout, tmp_path):
+    strategy, g = _layout(graphs, layout)
     ckdir = str(tmp_path / "ck")
-    full = run_pagerank_sharded(graph, CFG, n_devices=4)
+    full = run_pagerank_sharded(g, CFG, n_devices=4, strategy=strategy)
     partial = PageRankConfig(
         iterations=10, dangling="redistribute", init="uniform", dtype="float64",
         checkpoint_every=5, checkpoint_dir=ckdir,
     )
-    run_pagerank_sharded(graph, partial, n_devices=4)
+    run_pagerank_sharded(g, partial, n_devices=4, strategy=strategy)
     resume_cfg = PageRankConfig(
         iterations=30, dangling="redistribute", init="uniform", dtype="float64",
         checkpoint_every=5, checkpoint_dir=ckdir,
     )
-    res = run_pagerank_sharded(graph, resume_cfg, n_devices=4, resume=True)
+    res = run_pagerank_sharded(g, resume_cfg, n_devices=4, strategy=strategy,
+                               resume=True)
     np.testing.assert_allclose(res.ranks, full.ranks, atol=1e-12)
 
 
-def test_partition_edges_balanced(graph):
-    sg = partition_graph(graph, 8, strategy="edges")
-    # perfect balance: every device's slice is full except the last tail
-    assert sg.pad_frac < 8 / max(graph.n_edges, 1) + 0.01
+def test_partition_edges_balanced():
+    """The headless replicated layout deals equal contiguous slices of
+    the dst-sorted arcs: every device's slice is full but the last."""
+    g = _uniform_graph()
+    sg = partition_graph(g, 8, strategy="hybrid")
+    assert (sg.head_src == sg.n_pad).all()  # no head
+    assert int(sg.valid.sum()) == g.n_edges
+    assert sg.pad_frac < 8 / max(g.n_edges, 1) + 0.01
     assert (np.diff(sg.dst.ravel()[sg.valid.ravel() > 0]) >= 0).all()
 
 
-@pytest.mark.parametrize("strategy", ["nodes", "nodes_balanced"])
-def test_partition_nodes_covers_all_edges(graph, strategy):
-    sg = partition_graph(graph, 8, strategy=strategy)
-    assert int(sg.valid.sum()) == graph.n_edges
-    # dst_local within block bounds
-    assert (sg.dst >= 0).all() and (sg.dst < sg.block).all()
-    # node_map is a bijection into per-device slots
-    assert len(np.unique(sg.node_map)) == graph.n_nodes
-
-
-def test_partition_nodes_balanced_evens_powerlaw_edges():
-    """A hub-heavy graph: equal-node blocks concentrate in-edges on one
-    device; equal-edge boundaries must spread them to near-parity."""
+def _head_graph(hub_in: int):
+    """A hub taking ``hub_in`` in-arcs beside 1,000 arcs spread over 2,000
+    nodes (in-degree under the row width): the head is the hub alone."""
     rng = np.random.default_rng(0)
-    # 2000 nodes; node 0..3 receive ~90% of all edges (celebrities)
-    hubs = rng.integers(0, 4, 9000)
-    tail = rng.integers(4, 2000, 1000)
-    dst = np.concatenate([hubs, tail])
-    src = rng.integers(0, 2000, dst.size)
-    g = from_edges(src, dst)
-    plain = partition_graph(g, 8, strategy="nodes")
-    balanced = partition_graph(g, 8, strategy="nodes_balanced")
-
-    def max_real_edges(sg):
-        return int(sg.valid.sum(axis=1).max())
-
-    # plain 'nodes' puts ~all hub edges on device 0; balanced caps a device
-    # at roughly the largest single node's in-degree
-    assert max_real_edges(balanced) <= max_real_edges(plain) / 2
-    res_b = run_pagerank_sharded(
-        g, PageRankConfig(iterations=15, dangling="redistribute",
-                          init="uniform", dtype="float64"),
-        n_devices=8, strategy="nodes_balanced",
-    )
-    res_1 = run_pagerank(
-        g, PageRankConfig(iterations=15, dangling="redistribute",
-                          init="uniform", dtype="float64"),
-    )
-    assert np.abs(res_b.ranks - res_1.ranks).sum() <= 1e-9
+    dst = np.concatenate([np.zeros(hub_in, np.int64), np.arange(1, 1001)])
+    src = np.concatenate([rng.permutation(np.arange(1, 2000))[:hub_in],
+                          rng.integers(0, 2000, 1000)])
+    return from_edges(src, dst, compact_ids=False)
 
 
-def test_partition_src_covers_all_edges(graph):
-    sg = partition_graph(graph, 8, strategy="src")
-    assert int(sg.valid.sum()) == graph.n_edges
-    # sources are block-local; destinations are global padded ids, sorted
-    # per device row (pads at n_pad-1 keep the tail sorted)
-    assert (sg.src >= 0).all() and (sg.src < sg.block).all()
-    assert all((np.diff(row) >= 0).all() for row in sg.dst)
+def _weighted(g):
+    w = np.random.default_rng(1).uniform(0.5, 2.0, g.n_edges)
+    return from_edges(g.src, g.dst, weight=w, compact_ids=False)
 
 
-def test_ring_reduce_scatter_matches_psum_scatter():
-    """The explicit ppermute-ring exchange must agree with XLA's
-    psum_scatter bit-for-bit in f64 on every mesh size."""
-    import jax
-    import jax.numpy as jnp
-    from jax.sharding import PartitionSpec as P
-
-    from jax import shard_map
-
-    from page_rank_and_tfidf_using_apache_spark_tpu.parallel import collectives as coll
-
-    rng = np.random.default_rng(3)
-    for d in (1, 2, 4, 8):
-        mesh = make_mesh(d)
-        axis = mesh.axis_names[0]
-        x = rng.random((d, d * 16))  # one [D*B] partial per device
-        ring = shard_map(
-            lambda v: coll.ring_reduce_scatter(v[0], axis)[None, :],
-            mesh=mesh, in_specs=P(axis, None), out_specs=P(axis, None),
-            check_vma=False,
-        )
-        ref = shard_map(
-            lambda v: coll.reduce_scatter(v[0], axis)[None, :],
-            mesh=mesh, in_specs=P(axis, None), out_specs=P(axis, None),
-            check_vma=False,
-        )
-        got = np.asarray(jax.jit(ring)(x))
-        want = np.asarray(jax.jit(ref)(x))
-        np.testing.assert_allclose(got, want, atol=1e-12)
-        # and both equal the plain sum-then-shard
-        np.testing.assert_allclose(
-            got.ravel(), x.sum(axis=0), atol=1e-12)
+# graph, devices, per-chip budget -> the layout auto picks, and whether
+# that hybrid layout plans a dense head (None: ValueError)
+AUTO_TABLE = {
+    "head-28pct-d4": ("head400", 4, None, "hybrid", True),
+    "head-28pct-d8": ("head400", 8, None, "hybrid", True),
+    "head-17pct-d4": ("head200", 4, None, "hybrid", False),
+    "weighted-d8": ("weighted400", 8, None, "hybrid", False),
+    "fits-d3": ("head400", 3, None, "hybrid", True),
+    "starved-d4": ("head400", 4, 10_000, "owned", None),
+    "starved-d8": ("head400", 8, 10_000, "owned", None),
+    "starved-weighted-d8": ("weighted400", 8, 10_000, "owned", None),
+    "starved-d3": ("head400", 3, 10_000, None, None),
+    "starved-d6": ("head400", 6, 10_000, None, None),
+}
 
 
-def test_auto_select_strategy(graph, single_chip_ranks):
+@pytest.mark.parametrize("case", list(AUTO_TABLE))
+def test_auto_select_strategy(case):
+    """``auto``: ``hybrid`` while the replicated state fits half a chip,
+    else ``owned`` on a power-of-two mesh, else an error naming the
+    sizes; ``hybrid`` keeps a dense head only for an unweighted graph
+    whose head covers a quarter of the arcs (400 of 1,400: yes; 200 of
+    1,200: no)."""
     from page_rank_and_tfidf_using_apache_spark_tpu.parallel import (
         auto_select_strategy,
     )
 
-    # hub-heavy powerlaw graph, generous budget -> degree-aware 'hybrid'
-    # (the no-head and starved-budget pins live in test_hybrid_spmv.py)
-    assert auto_select_strategy(graph, 8) == "hybrid"
-    # starved budget -> the owned-slices layout (ISSUE 15: replicated-
-    # state-doesn't-fit is the owned trigger)
-    assert auto_select_strategy(graph, 8, hbm_bytes=10_000) == "owned"
-    res = run_pagerank_sharded(graph, CFG, n_devices=4, strategy="auto")
-    assert any(r.get("event") == "auto_strategy" for r in res.metrics.records)
-    assert np.abs(res.ranks - single_chip_ranks).sum() <= 1e-9
+    which, d, hbm, want, has_head = AUTO_TABLE[case]
+    hub = int(which[-3:])
+    g = _head_graph(hub)
+    if which.startswith("weighted"):
+        g = _weighted(g)
+    if want is None:
+        with pytest.raises(ValueError, match="power-of-two") as err:
+            auto_select_strategy(g, d, hbm_bytes=hbm)
+        assert f"{d} devices" in str(err.value)
+        return
+    assert auto_select_strategy(g, d, hbm_bytes=hbm) == want
+    if want == "hybrid":
+        plan = ps.plan_partition(g, d, strategy="hybrid")
+        assert (plan.head[0] > 0) is has_head, plan.head
 
 
 def test_spark_exact_sharded_raises(graph):

@@ -461,8 +461,8 @@ def test_sharded_entries_trace_the_shrink_chain():
         if ep.name.startswith("pagerank_sharded")
         or ep.name == "tfidf_sharded_ingest"
     ]
-    # edges/nodes_balanced/src/hybrid/owned + tfidf
-    assert len(sharded) == 6
+    # hybrid/owned + tfidf
+    assert len(sharded) == 3
     for ep in sharded:
         t = ep.build()
         labels = [label for label, _ in t.variants]

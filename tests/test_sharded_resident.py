@@ -26,7 +26,6 @@ from page_rank_and_tfidf_using_apache_spark_tpu.parallel import pagerank_sharded
 CFG = PageRankConfig(
     iterations=30, dangling="redistribute", init="uniform", dtype="float64"
 )
-POINTER_STRATEGIES = ["edges", "nodes", "nodes_balanced", "src", "src_ring", "hybrid"]
 
 
 @pytest.fixture(scope="module")
@@ -34,6 +33,30 @@ def graph():
     # 4 devices hold ~3,000 edges each: more than one 512-edge row, so
     # each shard's segment sum takes the scan
     return synthetic_powerlaw(2000, 12000, seed=42)
+
+
+def _weighted(g):
+    """The same arcs with weights: the replicated layout plans no dense
+    head for them, and carries the weights in its tail coefficients."""
+    w = np.random.default_rng(3).uniform(0.5, 2.0, g.n_edges)
+    return from_edges(g.src, g.dst, weight=w, compact_ids=False)
+
+
+@pytest.fixture(scope="module")
+def headless(graph):
+    g = _weighted(graph)
+    assert ps.plan_partition(g, 4).head[0] == 0
+    return g
+
+
+# layout name -> (strategy, whether its graph is the headless fixture)
+LAYOUTS = {"hybrid": ("hybrid", False), "hybrid-headless": ("hybrid", True),
+           "owned": ("owned", False)}
+
+
+def _pick(layout, graph, headless):
+    strategy, no_head = LAYOUTS[layout]
+    return strategy, (headless if no_head else graph)
 
 
 @pytest.fixture(scope="module")
@@ -45,8 +68,12 @@ def _records(metrics, event):
     return [r for r in metrics.records if r.get("event") == event]
 
 
-@pytest.mark.parametrize("strategy", ["edges", "hybrid", "owned", "auto"])
-def test_resident_jobs_match_one_shot_and_one_chip(graph, single_chip_ranks, strategy):
+@pytest.mark.parametrize("strategy", ["hybrid-headless", "hybrid", "owned", "auto"])
+def test_resident_jobs_match_one_shot_and_one_chip(graph, headless, single_chip_ranks,
+                                                   strategy):
+    if strategy == "hybrid-headless":
+        strategy, graph = "hybrid", headless
+        single_chip_ranks = run_pagerank(headless, CFG).ranks
     job = ShardedPageRank(graph, CFG, n_devices=4, strategy=strategy)
     first, second = job.run(), job.run()
     once = run_pagerank_sharded(graph, CFG, n_devices=4, strategy=strategy)
@@ -54,6 +81,9 @@ def test_resident_jobs_match_one_shot_and_one_chip(graph, single_chip_ranks, str
         assert res.iterations == 30
         assert np.abs(res.ranks - once.ranks).sum() <= 1e-12
         assert np.abs(res.ranks - single_chip_ranks).sum() <= 1e-9
+    if strategy == "auto":
+        assert job.strategy == "hybrid"
+        assert _records(job.metrics, "auto_strategy")
     (part,) = _records(job.metrics, "partition")
     assert part["segment_reduce"] == ("scatter" if job.strategy == "owned" else "scan")
     jobs = _records(job.metrics, "sharded_job")
@@ -61,8 +91,9 @@ def test_resident_jobs_match_one_shot_and_one_chip(graph, single_chip_ranks, str
     assert all(j["strategy"] == job.strategy and j["devices"] == 4 for j in jobs)
 
 
-@pytest.mark.parametrize("strategy", ["hybrid", "owned"])
-def test_second_job_partitions_puts_and_compiles_nothing(graph, strategy):
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_second_job_partitions_puts_and_compiles_nothing(graph, headless, layout):
+    strategy, graph = _pick(layout, graph, headless)
     compiles = []
 
     def listen(event, secs, **_kw):
@@ -87,14 +118,15 @@ def test_second_job_partitions_puts_and_compiles_nothing(graph, strategy):
 
 
 
-@pytest.mark.parametrize("strategy", ["hybrid", "owned"])
-def test_sharded_job_dispatches_under_its_span(graph, strategy):
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_sharded_job_dispatches_under_its_span(graph, headless, layout):
     """Each job dispatches under ``pagerank.dispatch`` and syncs under
     ``pagerank.delta_sync``, both inside its ``pagerank.sharded_job``
     span: the first job's fresh runner compiles inside the dispatch, the
     second job compiles nothing."""
     from page_rank_and_tfidf_using_apache_spark_tpu import obs
 
+    strategy, graph = _pick(layout, graph, headless)
     obs.watch_compiles()
     sink = obs.MemorySink()
     obs.bus().attach(sink)
@@ -132,16 +164,19 @@ def test_size_bucket_pads_under_a_thousandth():
     assert ps._size_bucket(130_900_447) == ps._size_bucket(130_901_943) == 130_940_928
 
 
-@pytest.mark.parametrize("strategy", ["edges", "hybrid"])
-def test_replicated_layouts_share_shapes_across_near_graphs(strategy):
+@pytest.mark.parametrize("headless", [True, False], ids=["hybrid-headless", "hybrid"])
+def test_replicated_layouts_share_shapes_across_near_graphs(headless):
     """Graphs a few arcs apart (two draws of one generator) partition to
-    the same shapes under the replicated layouts, so one compiled program
-    serves both."""
+    the same shapes under the replicated layout, with its dense head or
+    without one, so one compiled program serves both."""
     g = synthetic_powerlaw(50_000, 400_000, seed=8)
     near = from_edges(g.src[2:], g.dst[2:], compact_ids=False)
+    if headless:
+        g, near = _weighted(g), _weighted(near)
     assert near.n_nodes == g.n_nodes and near.n_edges == g.n_edges - 2
-    a = partition_graph(g, 4, strategy=strategy, dtype="float32")
-    b = partition_graph(near, 4, strategy=strategy, dtype="float32")
+    a = partition_graph(g, 4, dtype="float32")
+    b = partition_graph(near, 4, dtype="float32")
+    assert bool((a.head_src == a.n_pad).all()) is headless
     mesh = make_mesh(4)
     assert [x.shape for x, _ in ps.sharded_graph_layout(a, mesh)] == \
         [x.shape for x, _ in ps.sharded_graph_layout(b, mesh)]
@@ -164,15 +199,16 @@ def _lowered(sg, mesh):
         return ps.make_sharded_runner(sg, cfg, mesh).lower(vec, *args, vec).as_text()
 
 
-@pytest.mark.parametrize("strategy", POINTER_STRATEGIES)
-def test_shard_reduce_lowers_without_scatter(graph, strategy):
+@pytest.mark.parametrize("layout", ["hybrid-headless", "hybrid"])
+def test_shard_reduce_lowers_without_scatter(graph, headless, layout):
     """Through the slices' CSR pointers no shard scatters its segment sum.
-    ``hybrid``'s one scatter left is its head rows' sums, one per dense
-    row of the device."""
+    The one scatter left with a dense head is its rows' sums, one per
+    dense row of the device; without a head there is none."""
     mesh = make_mesh(4)
-    sg = partition_graph(graph, 4, strategy=strategy, dtype="float32")
-    assert ps.shard_segment_reduce(sg, "segment") == "scan"
-    head = [sg.head_node.shape[1]] if strategy == "hybrid" else []
+    _, g = _pick(layout, graph, headless)
+    sg = partition_graph(g, 4, dtype="float32")
+    assert ps.shard_segment_reduce(sg) == "scan"
+    head = [sg.head_node.shape[1]] if layout == "hybrid" else []
     assert _scatter_updates(_lowered(sg, mesh)) == head
 
 
@@ -187,16 +223,16 @@ def _hub_graph():
     return from_edges(src, dst, compact_ids=False)
 
 
-@pytest.mark.parametrize("strategy", ["edges", "hybrid"])
-def test_ragged_slices_hub_spans_devices(strategy):
-    g = _hub_graph()
+@pytest.mark.parametrize("headless", [True, False], ids=["hybrid-headless", "hybrid"])
+def test_ragged_slices_hub_spans_devices(headless):
+    g = _weighted(_hub_graph()) if headless else _hub_graph()
     cfg = PageRankConfig(iterations=20, dangling="redistribute", init="uniform",
                          dtype="float64", head_coverage=0.05)
-    sg = partition_graph(g, 4, strategy=strategy, dtype="float64")
+    sg = partition_graph(g, 4, dtype="float64")
     real = sg.valid > 0
     dst = np.where(real, sg.dst, -1)
     hub_devices = int((dst == 7).any(axis=1).sum())
-    if strategy == "edges":
+    if headless:
         assert hub_devices >= 2  # the hub's run spans devices
     # some device's slice starts inside a run its left neighbour holds
     starts_mid_run = [i for i in range(1, 4)
@@ -210,29 +246,31 @@ def test_ragged_slices_hub_spans_devices(strategy):
         want = np.bincount(sg.dst[i], weights=vals[i], minlength=sg.n_pad)
         np.testing.assert_allclose(c[ip[1:]] - c[ip[:-1]], want, rtol=0, atol=1e-11)
     base = run_pagerank(g, cfg).ranks
-    res = ShardedPageRank(g, cfg, n_devices=4, strategy=strategy).run()
+    res = ShardedPageRank(g, cfg, n_devices=4, strategy="hybrid").run()
     assert np.abs(res.ranks - base).sum() <= 1e-12
 
 
-def test_scan_reduce_holds_float32(graph):
+def test_scan_reduce_holds_float32(headless):
     """float32 ranks through the shards' scans stay as close to float64 as
-    the one-chip path's."""
+    the one-chip path's (every arc in the scanned tail: no dense head)."""
     cfg = PageRankConfig(iterations=20, dangling="redistribute", init="uniform",
                          dtype="float32")
-    exact = run_pagerank(graph, PageRankConfig(
+    exact = run_pagerank(headless, PageRankConfig(
         iterations=20, dangling="redistribute", init="uniform", dtype="float64")).ranks
     with jax.enable_x64(False):
-        one = run_pagerank(graph, cfg).ranks
-        got = ShardedPageRank(graph, cfg, n_devices=4, strategy="edges").run().ranks
+        one = run_pagerank(headless, cfg).ranks
+        got = ShardedPageRank(headless, cfg, n_devices=4, strategy="hybrid").run().ranks
     assert np.abs(got - exact).sum() <= 3 * max(np.abs(one - exact).sum(), 1e-7)
 
 
-@pytest.mark.parametrize("strategy", ["hybrid", "owned"])
-def test_chunked_gathers_keep_ranks_bit_identical(graph, strategy, monkeypatch):
+@pytest.mark.parametrize("layout", list(LAYOUTS))
+def test_chunked_gathers_keep_ranks_bit_identical(graph, headless, layout, monkeypatch):
     """With a cap under each shard's tail and head index counts, every
     per-edge gather of the step runs in chunks (ragged ones included: 967
     tail arcs a device under ``hybrid``, 1,930 head slots under ``owned``),
-    and the ranks are those of the whole gathers, bit for bit."""
+    and the ranks are those of the whole gathers, bit for bit.  Without a
+    dense head the one gather is the tail's."""
+    strategy, graph = _pick(layout, graph, headless)
     whole = ShardedPageRank(graph, CFG, n_devices=4, strategy=strategy)
     ranks = whole.run().ranks
     monkeypatch.setattr(ops, "GATHER_CAP", 100)
@@ -241,4 +279,5 @@ def test_chunked_gathers_keep_ranks_bit_identical(graph, strategy, monkeypatch):
     (part,) = _records(whole.metrics, "partition")
     assert part["gather_chunks"] == {"tail": 1, "head": 1}
     (part,) = _records(chunked.metrics, "partition")
-    assert part["gather_chunks"]["tail"] > 1 and part["gather_chunks"]["head"] > 1
+    assert part["gather_chunks"]["tail"] > 1
+    assert (part["gather_chunks"]["head"] > 1) is (layout != "hybrid-headless")

@@ -148,20 +148,27 @@ def test_serve_scoring_compiles_for_v5e(one_chip, f32):
     assert _device_bytes(compiled) < V5E_HBM_BYTES
 
 
-@pytest.mark.parametrize("strategy", ["auto", "owned"])
+@pytest.mark.parametrize("strategy", ["auto", "auto-headless", "owned"])
 def test_sharded_runner_compiles_for_v5e_mesh(strategy, topo, f32):
     """The 4-chip sharded PageRank program, collectives included, on a mesh
     of the described chips: ``auto`` (what the selector picks for a graph
-    whose replicated state fits) and ``owned`` (the memory-scaling
-    layout)."""
+    whose replicated state fits: ``hybrid``, with a dense head, and
+    without one for the same arcs weighted) and ``owned`` (the
+    memory-scaling layout)."""
     from page_rank_and_tfidf_using_apache_spark_tpu.parallel import (
         pagerank_sharded as ps,
     )
     from page_rank_and_tfidf_using_apache_spark_tpu.parallel.mesh import NODES_AXIS
 
+    from page_rank_and_tfidf_using_apache_spark_tpu.io.graph import from_edges
+
     g = synthetic_powerlaw(200_000, 1_200_000, seed=7)
-    if strategy == "auto":
+    if strategy == "auto-headless":
+        w = np.random.default_rng(1).uniform(0.5, 2.0, g.n_edges).astype(np.float32)
+        g = from_edges(g.src, g.dst, weight=w, compact_ids=False)
+    if strategy.startswith("auto"):
         strategy = ps.auto_select_strategy(g, 4, hbm_bytes=V5E_HBM_BYTES)
+        assert strategy == "hybrid"
     mesh = Mesh(np.array(topo.devices[:4]), (NODES_AXIS,))
     cfg = PageRankConfig(iterations=20, dangling="redistribute", init="uniform",
                          dtype="float32")
@@ -180,10 +187,9 @@ def test_sharded_runner_compiles_for_v5e_mesh(strategy, topo, f32):
                  jax.ShapeDtypeStruct((), jnp.float32, sharding=repl))
         args = (state, *graph_args, state[0], state[1])
     else:
-        vec_sh = NamedSharding(
-            mesh, P() if sg.strategy in ("edges", "hybrid") else P(NODES_AXIS)
-        )
-        vec = jax.ShapeDtypeStruct((sg.n_pad,), jnp.float32, sharding=vec_sh)
+        assert bool((sg.head_src == sg.n_pad).all()) is (g.weight is not None)
+        vec = jax.ShapeDtypeStruct((sg.n_pad,), jnp.float32,
+                                   sharding=NamedSharding(mesh, P()))
         args = (vec, *graph_args, vec)
     compiled = ps.make_sharded_runner(sg, cfg, mesh).lower(*args).compile()
     text = compiled.as_text()
@@ -215,7 +221,7 @@ def test_sharded_hybrid_gathers_read_the_rank_table_from_vmem(topo, f32):
         src=zeros((d, e_dev), np.int32), dst=zeros((d, e_dev), np.int32),
         valid=zeros((d, e_dev), np.float32),
         inv_outdeg=zeros((n_pad,), np.float32),
-        dangling=zeros((n_pad,), np.float32), pad_frac=0.0, node_map=None,
+        dangling=zeros((n_pad,), np.float32), pad_frac=0.0,
         local_indptr=zeros((d, n_pad + 1), np.int32),
         head_src=zeros((d, rows, width), np.int32),
         head_node=zeros((d, rows), np.int32),

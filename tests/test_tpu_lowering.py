@@ -57,29 +57,36 @@ def test_pagerank_tolerance_runner_lowers_for_tpu(device_graph):
     assert export.export(runner, platforms=["tpu"])(dg, r0, e).mlir_module()
 
 
-@pytest.mark.parametrize("impl", ["segment", "cumsum", "cumsum_mxu"])
-@pytest.mark.parametrize("strategy", ["edges", "nodes", "nodes_balanced", "src", "src_ring"])
-def test_sharded_runner_lowers_for_tpu(strategy, impl):
+@pytest.mark.parametrize("dangling", ["redistribute", "drop"])
+@pytest.mark.parametrize("tol", [0.0, 1e-8], ids=["scan", "while"])
+@pytest.mark.parametrize("layout", ["hybrid", "hybrid-headless", "owned"])
+def test_sharded_runner_lowers_for_tpu(layout, tol, dangling):
     """The multi-chip shard_map program (collectives included) must lower
-    for the TPU platform — the CPU dryrun alone cannot prove that."""
+    for the TPU platform — the CPU dryrun alone cannot prove that — under
+    a fixed iteration count (a scan) and a tolerance (a while loop)."""
     import numpy as np
 
-    from page_rank_and_tfidf_using_apache_spark_tpu.parallel import make_mesh
+    from page_rank_and_tfidf_using_apache_spark_tpu.io.graph import from_edges
     from page_rank_and_tfidf_using_apache_spark_tpu.parallel import (
-        pagerank_sharded as ps,
+        ShardedPageRank,
+        make_mesh,
     )
 
     g = synthetic_powerlaw(2000, 10000, seed=1)
-    mesh = make_mesh(8)
-    cfg = PageRankConfig(iterations=3, dangling="redistribute", init="uniform",
-                         dtype="float32", spmv_impl=impl)
-    sg = ps.partition_graph(g, 8, strategy=strategy, dtype="float32")
-    runner = ps.make_sharded_runner(sg, cfg, mesh)
-    dev = ps.device_put_sharded_graph(sg, mesh)
-    e_vec = jnp.asarray(ps._restart_padded(sg, cfg))
-    r0 = jnp.asarray(ps._to_padded(sg, np.full(sg.n, 1.0 / sg.n, np.float32),
-                                   "float32"))
-    exp = export.export(runner, platforms=["tpu"])(r0, *dev, e_vec)
+    if layout == "hybrid-headless":  # weighted: no dense head
+        w = np.random.default_rng(2).uniform(0.5, 2.0, g.n_edges)
+        g = from_edges(g.src, g.dst, weight=w, compact_ids=False)
+    cfg = PageRankConfig(iterations=3, tol=tol, dangling=dangling,
+                         init="uniform", dtype="float32")
+    with jax.enable_x64(False):
+        job = ShardedPageRank(g, cfg, mesh=make_mesh(8),
+                              strategy=layout.split("-")[0])
+        sg, ex = job.exec.sg, job.exec
+        assert (sg.strategy == "owned"
+                or bool((sg.head_src != sg.n_pad).any()) is (layout == "hybrid"))
+        runner = ex.make_runner(cfg)
+        args = ex.args(ex.put_ranks(ops.init_ranks(sg.n, cfg)))
+        exp = export.export(runner, platforms=["tpu"])(*args)
     assert exp.mlir_module()
 
 
